@@ -37,7 +37,7 @@ def run():
         # decode greedily, collecting branch outputs per position
         tok = jnp.argmax(T.forward(cfg, params, toks,
                                    moe_strategy="dense")[0][:, -1:], -1)
-        per_pos = []
+        stream = calibration.ErrorCurveStream(cfg, k_max=2)
         for i in range(gen):
             x = T.embed_tokens(cfg, params, tok)
             x, branch, new_caches, _ = T.apply_stages(
@@ -47,10 +47,9 @@ def run():
                 cfg, params,
                 L.apply_norm(cfg.norm, params["final_norm"], x))
             caches = new_caches
-            per_pos.append(calibration.branch_outputs_by_type(cfg, branch))
+            stream.push(branch)
             tok = jnp.argmax(x, -1)
-        curves, _ = calibration.error_curves_from_trajectory(cfg, per_pos,
-                                                             k_max=2)
+        curves, _ = stream.curves()
         for t, c in curves.items():
             m = float(np.nanmean(c[1:, 1]))
             common.emit(f"beyond_ar/{arch}/{t}", 0.0,

@@ -11,6 +11,8 @@ import sys
 import time
 import traceback
 
+from repro import compile_cache
+
 MODULES = [
     ("fig5", "benchmarks.fig5_compute"),        # fast, analytic
     ("fig2", "benchmarks.fig2_error_curves"),
@@ -40,6 +42,7 @@ def main() -> None:
                     help="comma-separated subset of: "
                          + ",".join(k for k, _ in MODULES))
     args = ap.parse_args()
+    compile_cache.enable()
     only = set(args.only.split(",")) if args.only else None
     print("name,us_per_call,derived")
     failures = []

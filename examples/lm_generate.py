@@ -13,7 +13,7 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro import configs
+from repro import compile_cache, configs
 from repro.data import TokenStream, text_memory, vit_patch_embeds
 from repro.launch.serve import generate
 from repro.models import transformer as T
@@ -48,4 +48,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks import common
-from repro import cache, configs
+from repro import cache, compile_cache, configs
 from repro.core import solvers
 from repro.data import BlobLatents
 
@@ -70,4 +70,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -28,7 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks import common
-from repro import cache, configs, serve
+from repro import cache, compile_cache, configs, serve
 from repro.core import solvers
 from repro.core.executor import SmoothCacheExecutor
 
@@ -143,4 +143,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

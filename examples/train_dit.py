@@ -14,7 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks import common
-from repro import checkpoint, configs
+from repro import checkpoint, compile_cache, configs
 from repro.core import diffusion, solvers
 from repro.core.executor import SmoothCacheExecutor
 from repro.data import BlobLatents, CondLatents
@@ -61,4 +61,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

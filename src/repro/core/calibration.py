@@ -34,60 +34,92 @@ import numpy as np
 from repro.config import ModelConfig
 
 
-def branch_outputs_by_type(cfg: ModelConfig, branch_tree) -> Dict[str, List[np.ndarray]]:
-    """Flatten the per-stage scan-stacked branch outputs into
-    {type: [per-layer arrays (B, N, d)] in depth order}."""
-    out: Dict[str, List[np.ndarray]] = {}
+def _branches_by_type(cfg: ModelConfig, branch_tree, rows: Optional[int]
+                      ) -> Dict[str, List[jax.Array]]:
+    """Group the per-stage scan-stacked branch outputs by layer type:
+    {type: [(repeat, B, ...) arrays] in depth order}, keeping the leading
+    ``rows`` batch rows when given.  Arrays stay where they are (on device
+    for a sampling pass)."""
+    out: Dict[str, List[jax.Array]] = {}
     for si, st in enumerate(cfg.stages):
         stage_branches = branch_tree[si]          # tuple per block in unit
         for bi, b in enumerate(st.unit):
             bo = stage_branches[bi]
-            names = b.branch_names()
-            types = b.branch_types()
-            for name, t in zip(names, types):
+            for name, t in zip(b.branch_names(), b.branch_types()):
                 if bo is None or name not in bo:
                     continue
-                arr = np.asarray(bo[name])        # (repeat, B, N, d)
-                for r in range(arr.shape[0]):
-                    out.setdefault(t, []).append(arr[r])
+                arr = bo[name]                    # (repeat, B, N, d)
+                out.setdefault(t, []).append(
+                    arr if rows is None else arr[:, :rows])
     return out
 
 
-def l1_rel_error(a: np.ndarray, b: np.ndarray, axis=None) -> np.ndarray:
-    """||a − b||₁ / ||a||₁ (per-sample when axis keeps the batch dim)."""
-    num = np.sum(np.abs(a - b), axis=axis)
-    den = np.sum(np.abs(a), axis=axis) + 1e-12
-    return num / den
+@jax.jit
+def _pair_errors(cur: Dict[str, List[jax.Array]],
+                 prev: Dict[str, List[jax.Array]]) -> Dict[str, jax.Array]:
+    """||cur − prev||₁ / ||cur||₁ per layer and sample, reduced over every
+    axis but (layer, batch): {type: (layers, B)}."""
+    out = {}
+    for t in cur:
+        errs = []
+        for c, p in zip(cur[t], prev[t]):
+            ax = tuple(range(2, c.ndim))
+            num = jnp.sum(jnp.abs(c - p), axis=ax)
+            den = jnp.sum(jnp.abs(c), axis=ax) + 1e-12
+            errs.append(num / den)
+        out[t] = jnp.concatenate(errs, axis=0)
+    return out
 
 
-def error_curves_from_trajectory(cfg: ModelConfig,
-                                 per_step: List[Dict[str, List[np.ndarray]]],
-                                 k_max: int = 3):
-    """per_step[s] = branch_outputs_by_type at sampling step s.
+class ErrorCurveStream:
+    """Streaming builder of the Fig. 2 error curves.  :meth:`push` takes
+    one step's branch tree; only the last ``k_max`` steps are kept, where
+    the tree lives (on device for a sampling pass), and each push reads
+    back just the (layers, B) error matrices.  Memory is therefore
+    independent of the step count — holding every step's branch outputs
+    on the host would take ~33 GB for 10 DiT-XL samples over 50 steps.
 
-    Returns (mean_curves {t: (S, K+1)}, per_sample {t: (B, S, K+1)}).
-    Entries with k > s are NaN; k=0 column is 0.
-    """
-    s_total = len(per_step)
-    types = sorted(per_step[0].keys())
-    bsz = per_step[0][types[0]][0].shape[0]
-    mean_curves = {t: np.full((s_total, k_max + 1), np.nan) for t in types}
-    per_sample = {t: np.full((bsz, s_total, k_max + 1), np.nan) for t in types}
-    for t in types:
-        for s in range(s_total):
-            per_sample[t][:, s, 0] = 0.0
-            mean_curves[t][s, 0] = 0.0
-            for k in range(1, min(k_max, s) + 1):
-                errs = []
-                for lj, (cur, prev) in enumerate(zip(per_step[s][t],
-                                                     per_step[s - k][t])):
-                    # per-sample L1 over all non-batch axes
-                    ax = tuple(range(1, cur.ndim))
-                    errs.append(l1_rel_error(cur, prev, axis=ax))
-                e = np.mean(np.stack(errs, 0), axis=0)   # layer-mean, (B,)
-                per_sample[t][:, s, k] = e
-                mean_curves[t][s, k] = float(np.mean(e))
-    return mean_curves, per_sample
+    ``rows`` keeps the leading batch rows only (the conditioned half of a
+    CFG-doubled batch)."""
+
+    def __init__(self, cfg: ModelConfig, k_max: int = 3,
+                 rows: Optional[int] = None):
+        self.cfg = cfg
+        self.k_max = k_max
+        self.rows = rows
+        self._window: List[Dict[str, List[jax.Array]]] = []
+        self._bsz = 0
+        #: per step: {type: {k: (B,) layer-mean error}}, 1 ≤ k ≤ min(k_max, s)
+        self._errs: List[Dict[str, Dict[int, np.ndarray]]] = []
+
+    def push(self, branch_tree) -> None:
+        cur = _branches_by_type(self.cfg, branch_tree, self.rows)
+        self._bsz = next(iter(cur.values()))[0].shape[1]
+        errs: Dict[str, Dict[int, np.ndarray]] = {t: {} for t in cur}
+        for k, prev in enumerate(reversed(self._window), start=1):
+            for t, e in _pair_errors(cur, prev).items():
+                errs[t][k] = np.mean(np.asarray(e), axis=0)   # layer mean
+        self._errs.append(errs)
+        self._window = (self._window + [cur])[-self.k_max:]
+
+    def curves(self) -> Tuple[Dict[str, np.ndarray], Dict[str, np.ndarray]]:
+        """(mean_curves {t: (S, K+1)}, per_sample {t: (B, S, K+1)}).
+        Entries with k > s are NaN; the k=0 column is 0."""
+        s_total = len(self._errs)
+        types = sorted(self._errs[0])
+        bsz, k_max = self._bsz, self.k_max
+        mean_curves = {t: np.full((s_total, k_max + 1), np.nan)
+                       for t in types}
+        per_sample = {t: np.full((bsz, s_total, k_max + 1), np.nan)
+                      for t in types}
+        for t in types:
+            for s, errs in enumerate(self._errs):
+                per_sample[t][:, s, 0] = 0.0
+                mean_curves[t][s, 0] = 0.0
+                for k, e in errs[t].items():
+                    per_sample[t][:, s, k] = e
+                    mean_curves[t][s, k] = float(np.mean(e))
+        return mean_curves, per_sample
 
 
 # ---------------------------------------------------------------------------
@@ -282,26 +314,19 @@ def calibrate_record(executor, params, key, batch: int, *, cond_args=None,
     exactly ``batch``)."""
     cond_args = cond_args or {}
     cfg_halved = executor.cfg_scale is not None
-    per_step: List[Dict[str, List[np.ndarray]]] = []
-
-    def hook(s, branch_tree):
-        by_type = branch_outputs_by_type(executor.cfg, branch_tree)
-        if cfg_halved:
-            # keep the conditioned half of the [cond; uncond] doubled batch
-            by_type = {t: [a[:batch] for a in arrs]
-                       for t, arrs in by_type.items()}
-        per_step.append(by_type)
+    # keep the conditioned half of the [cond; uncond] doubled batch
+    stream = ErrorCurveStream(executor.cfg, k_max,
+                              rows=batch if cfg_halved else None)
 
     x_init, _ = executor.initial_latent(key, batch)
     x0, traj = executor.sample(params, key, batch, schedule=None,
-                               collect_hook=hook, return_trajectory=True,
-                               **cond_args)
+                               collect_hook=lambda s, tree: stream.push(tree),
+                               return_trajectory=True, **cond_args)
     # model input at step s: the initial noise for s=0, else the latent
     # produced by step s−1
     inputs = [np.asarray(x_init)] + [np.asarray(x) for x in traj[:-1]]
     proxies = proxies_from_inputs(inputs)
-    curves, per_sample = error_curves_from_trajectory(
-        executor.cfg, per_step, k_max=k_max)
+    curves, per_sample = stream.curves()
     return CalibrationRecord(
         curves=curves, per_sample=per_sample, proxies=proxies,
         proxy_map=fit_proxy_map(curves, proxies), x0=np.asarray(x0),

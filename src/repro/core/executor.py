@@ -432,19 +432,12 @@ class SmoothCacheExecutor:
         """Actual XLA executable count behind the variant table: each jitted
         entry holds one compilation per distinct input *shape* (a serving
         engine's batch-size buckets multiply here — the program-budget bound
-        is |buckets| × |signatures|).  Falls back to one per entry when the
-        jit cache size is not introspectable (non-jit mode, older jax)."""
+        is |buckets| × |signatures|).  Counts one per entry in non-jit
+        mode, where the entries are plain functions."""
         total = 0
         for k in self.fn_keys(kind):
-            fn = self._fns[k]
-            n = None
-            cache_size = getattr(fn, "_cache_size", None)
-            if callable(cache_size):
-                try:
-                    n = int(cache_size())
-                except Exception:
-                    n = None
-            total += n if n is not None else 1
+            cache_size = getattr(self._fns[k], "_cache_size", None)
+            total += cache_size() if cache_size is not None else 1
         return total
 
     # -- plan resolution -----------------------------------------------------

@@ -1,14 +1,14 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On TPU the kernels lower natively through ``pl.pallas_call``; everywhere
-else (this CPU container, unit tests) they execute in interpret mode, which
-runs the kernel body in Python per grid cell — bit-accurate to the TPU
-blocking, just slow.  ``REPRO_KERNEL_INTERPRET=0/1`` overrides detection.
+The kernels lower natively through ``pl.pallas_call`` (Mosaic on TPU).
+Interpret mode — the kernel body run per grid cell through XLA,
+bit-accurate to the TPU blocking, just slow — runs only when the caller
+passes ``interpret=True`` (the CPU tests do); a native call off a TPU
+fails at lowering instead of silently falling back.
 """
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import jax
@@ -18,13 +18,6 @@ from repro.kernels import flash_attention as _fa
 from repro.kernels import ssd as _ssd
 
 
-def _interpret_default() -> bool:
-    env = os.environ.get("REPRO_KERNEL_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=(
     "causal", "window", "softcap", "scale", "block_q", "block_k", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True,
@@ -32,9 +25,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     softcap: Optional[float] = None,
                     scale: Optional[float] = None,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: Optional[bool] = None):
-    if interpret is None:
-        interpret = _interpret_default()
+                    interpret: bool = False):
     return _fa.flash_attention(q, k, v, causal=causal, window=window,
                                softcap=softcap, scale=scale, block_q=block_q,
                                block_k=block_k, interpret=interpret)
@@ -42,7 +33,5 @@ def flash_attention(q, k, v, *, causal: bool = True,
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd(x, dt, a, b, c, *, chunk: int = 128,
-        interpret: Optional[bool] = None):
-    if interpret is None:
-        interpret = _interpret_default()
+        interpret: bool = False):
     return _ssd.ssd(x, dt, a, b, c, chunk=chunk, interpret=interpret)

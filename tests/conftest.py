@@ -2,7 +2,7 @@ import os
 
 # Tests run on the single local CPU device (the dry-run, and ONLY the
 # dry-run, forces 512 placeholder devices — see src/repro/launch/dryrun.py).
-os.environ.setdefault("REPRO_KERNEL_INTERPRET", "1")
+# Pallas kernels run in interpret mode only where a test asks for it.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import jax  # noqa: E402

@@ -1,4 +1,6 @@
 """Per-kernel allclose sweeps vs. the pure-jnp oracles (interpret mode)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -7,6 +9,15 @@ import pytest
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.ref import flash_attention_ref, ssd_ref, ssd_sequential_ref
 from repro.kernels.ssd import ssd
+
+
+def _interpret_kernels(monkeypatch):
+    """Route the model's kernel calls through interpret mode: the wrappers
+    lower natively unless the caller passes ``interpret=True``."""
+    from repro.kernels import ops
+    for name in ("flash_attention", "ssd"):
+        monkeypatch.setattr(ops, name, functools.partial(
+            getattr(ops, name), interpret=True))
 
 
 def _tol(dtype):
@@ -115,14 +126,14 @@ def test_ssd_initial_state():
                                rtol=1e-4)
 
 
-def test_model_forward_with_flash_kernel_matches():
+def test_model_forward_with_flash_kernel_matches(monkeypatch):
     """use_flash=True routes attention through the Pallas kernel (interpret
-    mode on CPU) — must match the jnp path through a whole model."""
-    import os
-    os.environ["REPRO_KERNEL_INTERPRET"] = "1"
+    mode on CPU, asked for explicitly) — must match the jnp path through a
+    whole model."""
     from repro import configs
     from repro.models import transformer as T
 
+    _interpret_kernels(monkeypatch)
     cfg = configs.get("qwen3-14b", "smoke")
     params = T.init_params(jax.random.PRNGKey(0), cfg)
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0,
@@ -133,12 +144,11 @@ def test_model_forward_with_flash_kernel_matches():
                                atol=2e-3, rtol=2e-3)
 
 
-def test_ssm_model_with_kernel_matches():
-    import os
-    os.environ["REPRO_KERNEL_INTERPRET"] = "1"
+def test_ssm_model_with_kernel_matches(monkeypatch):
     from repro import configs
     from repro.models import transformer as T
 
+    _interpret_kernels(monkeypatch)
     cfg = configs.get("mamba2-1.3b", "smoke")
     params = T.init_params(jax.random.PRNGKey(0), cfg)
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0,

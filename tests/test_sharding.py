@@ -9,17 +9,9 @@ from jax.sharding import AbstractMesh, PartitionSpec as P
 from repro import configs
 from repro.launch import hlo_analysis, programs, sharding
 
-def _abstract_mesh(sizes, names):
-    """jax >= 0.5 takes (axis_sizes, axis_names); 0.4.x takes one
-    tuple of (name, size) pairs."""
-    try:
-        return AbstractMesh(sizes, names)
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, sizes)))
 
-
-MESH_1POD = _abstract_mesh((16, 16), ("data", "model"))
-MESH_2POD = _abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH_1POD = AbstractMesh((16, 16), ("data", "model"))
+MESH_2POD = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _axis_prod(mesh, axes):

@@ -146,6 +146,43 @@ def test_calibration_curve_invariants(small_dit):
         assert per_sample[t].shape == (3, 6, 4)
 
 
+@pytest.mark.parametrize("rows", [None, 2])
+def test_error_curve_stream_matches_loop_reference(rows):
+    """The streaming curves (device-side errors, a k_max-step window) equal
+    the per-layer host loop over every stored step, to float32 rounding;
+    ``rows`` keeps the conditioned half of a CFG-doubled batch."""
+    cfg = configs.get("dit-xl-256", "smoke")
+    repeat = cfg.stages[0].repeat
+    names = cfg.stages[0].unit[0].branch_names()
+    types = cfg.stages[0].unit[0].branch_types()
+    k_max, steps, batch = 3, 6, 4
+    rng = np.random.RandomState(0)
+    trees = [[({n: rng.randn(repeat, batch, 5, 3).astype(np.float32)
+                for n in names},)] for _ in range(steps)]
+    stream = calibration.ErrorCurveStream(cfg, k_max, rows=rows)
+    for tree in trees:
+        stream.push(jax.tree.map(jnp.asarray, tree))
+        assert len(stream._window) <= k_max
+    curves, per_sample = stream.curves()
+
+    keep = batch if rows is None else rows
+    for n, t in zip(names, types):
+        want = np.full((keep, steps, k_max + 1), np.nan)
+        want[:, :, 0] = 0.0
+        for s in range(steps):
+            for k in range(1, min(k_max, s) + 1):
+                errs = []
+                for r in range(repeat):
+                    cur = trees[s][0][0][n][r, :keep].astype(np.float64)
+                    prev = trees[s - k][0][0][n][r, :keep].astype(np.float64)
+                    errs.append(np.abs(cur - prev).sum(axis=(1, 2))
+                                / np.abs(cur).sum(axis=(1, 2)))
+                want[:, s, k] = np.mean(errs, axis=0)
+        np.testing.assert_allclose(per_sample[t], want, rtol=1e-5)
+        np.testing.assert_allclose(curves[t], np.mean(want, axis=0),
+                                   rtol=1e-5)
+
+
 def test_solver_step_counts(small_dit):
     cfg, params = small_dit
     for mk in (solvers.ddim(5), solvers.rectified_flow(5),

@@ -1,0 +1,85 @@
+"""Compile rehearsals for a TPU v5e that is described, not attached.
+
+The TPU compiler compiles here for a ``v5e:2x2`` topology description and
+runs nothing: it refuses what the chip would refuse (unaligned tiles, too
+much VMEM, a program that does not fit HBM) at no chip time.  The
+topology is described inside a fixture — never at import — so every
+test worker collects the same tests and only the worker given this file
+loads the TPU library.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro import configs
+from repro.core import diffusion
+from repro.kernels import ops
+
+#: one v5e chip's HBM
+V5E_HBM_BYTES = 16 * 10 ** 9
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    with pytest.MonkeyPatch.context() as mp:
+        # the TPU library would otherwise log under the system temp dir
+        mp.setenv("TPU_LOG_DIR", os.environ.get("TPU_LOG_DIR", "disabled"))
+        # a compile for a described chip can be written to the persistent
+        # cache but never read back; keep the cache out of these tests
+        prev = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        try:
+            try:
+                yield topologies.get_topology_desc(platform="tpu",
+                                                   topology_name="v5e:2x2")
+            except Exception as e:
+                pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        finally:
+            jax.config.update("jax_enable_compilation_cache", prev)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("shape", [
+    (8, 256, 16, 72),       # DiT-XL/2: 4 requests under CFG, 256 tokens
+    (8, 216, 24, 64),       # Stable Audio Open: 216 tokens → padding path
+], ids=["dit_xl", "stable_audio"])
+def test_flash_attention_compiles_natively(one_chip, shape, dtype):
+    s = jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=one_chip)
+    compiled = ops.flash_attention.lower(s, s, s, causal=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_dit_xl_forward_fits_one_chip(one_chip):
+    """Full-width DiT-XL/2 denoiser forward, 8 rows (4 requests under CFG),
+    collecting every branch as calibration does."""
+    cfg = configs.get("dit-xl-256", "full")
+    params = jax.eval_shape(
+        lambda: diffusion.init_params(jax.random.PRNGKey(0), cfg))
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        params)
+    rows = 8
+    x = jax.ShapeDtypeStruct((rows,) + tuple(cfg.latent_shape), jnp.float32,
+                             sharding=one_chip)
+    t = jax.ShapeDtypeStruct((rows,), jnp.float32, sharding=one_chip)
+    label = jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=one_chip)
+
+    def forward(p, x, t, label):
+        return diffusion.apply(cfg, p, x, t, label=label,
+                               collect_branches=True)
+
+    compiled = jax.jit(forward).lower(params, x, t, label).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.output_size_in_bytes)
+    assert mem.argument_size_in_bytes > 2.5e9     # f32 params at full width
+    assert total < V5E_HBM_BYTES
