@@ -1,0 +1,7 @@
+"""Device self time under the model's ``ffn`` scope over the device's
+busy time in the profiler trace, in percent (``trace_scopes.py``)."""
+import trace_scopes
+
+
+def read(run):
+    return trace_scopes.share(run, "ffn")

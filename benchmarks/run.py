@@ -23,7 +23,6 @@ MODULES = [
     ("slo", "benchmarks.slo_bench"),
     ("resilience", "benchmarks.resilience_bench"),
     ("continuous", "benchmarks.continuous_bench"),
-    ("obs", "benchmarks.obs_bench"),
     ("durability", "benchmarks.durability_bench"),
     ("table2", "benchmarks.table2_video"),
     ("table3", "benchmarks.table3_audio"),

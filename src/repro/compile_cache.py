@@ -10,6 +10,13 @@ itself), else ``<checkout>/.jax_cache``.
 Called by the entry points (``chip_smoke.py``, ``examples/*.py``,
 ``benchmarks/run.py``) before their first compile — never on library
 import and never from the tests.
+
+The key includes each op's metadata: profiles attribute device time by
+the model's name scopes (``attn``, ``ffn``, …), and JAX's default key
+leaves metadata out, so a program that differs from a cached one only in
+its scopes would load the cached executable and trace under its stale
+names.  Source locations are left out of the metadata, so the key does
+not move with the checkout's path or an edit's line numbers.
 """
 from __future__ import annotations
 
@@ -25,6 +32,8 @@ CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
 def enable() -> str:
     """Turn the persistent compilation cache on and return its directory.
     Leaves the choice to JAX when ``JAX_COMPILATION_CACHE_DIR`` is set."""
+    jax.config.update("jax_traceback_in_locations_limit", 0)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

@@ -117,6 +117,10 @@ def apply(cfg: ModelConfig, params, x, t, *, label=None, memory=None,
           skip=None, branch_caches=None, collect_branches=False,
           use_flash=False):
     """Denoiser: x (B, *latent_shape), t (B,) → prediction (B, *latent_shape).
+    Its parts run under ``jax.named_scope``s: ``embed`` and ``final`` here,
+    and in each block ``adaln`` and the branch's SmoothCache type
+    (``attn``, ``xattn``, ``ffn``), so a profiler trace names each op's
+    branch in the cache's own vocabulary.
 
     Returns (pred, aux) with aux["branch"] holding per-layer pre-residual
     branch outputs (the SmoothCache payload) when requested.
@@ -124,22 +128,27 @@ def apply(cfg: ModelConfig, params, x, t, *, label=None, memory=None,
     executor's liveness analysis passes the exact set of types whose fresh
     outputs a later step will read, so dead branches are never stacked."""
     _, _, video_shape = token_shape(cfg)
-    tok = patchify(cfg, x)
-    h = tok @ params["patch_in"]["w"] + params["patch_in"]["b"]
-    # fixed sin-cos positional embedding over flattened tokens (DiT-style)
-    pos = jnp.arange(h.shape[1])
-    h = h + L.sinusoidal_embedding(pos, cfg.d_model)[None].astype(h.dtype)
-    cond = _cond_vector(cfg, params, t, label)
+    with jax.named_scope("embed"):
+        tok = patchify(cfg, x)
+        h = tok @ params["patch_in"]["w"] + params["patch_in"]["b"]
+        # fixed sin-cos positional embedding over flattened tokens
+        # (DiT-style)
+        pos = jnp.arange(h.shape[1])
+        h = h + L.sinusoidal_embedding(pos, cfg.d_model)[None].astype(
+            h.dtype)
+        cond = _cond_vector(cfg, params, t, label)
     out, aux = T.forward(
         cfg, params["backbone"], embeds=h, memory=memory, cond=cond,
         skip=skip, branch_caches=branch_caches,
         collect_branches=collect_branches,
         use_flash=use_flash, video_shape=video_shape)
-    mod = jax.nn.silu(cond) @ params["final_mod"]["w"] + params["final_mod"]["b"]
-    shift, scale = jnp.split(mod[:, None, :], 2, axis=-1)
-    out = out * (1.0 + scale) + shift
-    out = out @ params["out"]["w"] + params["out"]["b"]
-    return unpatchify(cfg, out), aux
+    with jax.named_scope("final"):
+        mod = (jax.nn.silu(cond) @ params["final_mod"]["w"]
+               + params["final_mod"]["b"])
+        shift, scale = jnp.split(mod[:, None, :], 2, axis=-1)
+        out = out * (1.0 + scale) + shift
+        out = out @ params["out"]["w"] + params["out"]["b"]
+        return unpatchify(cfg, out), aux
 
 
 # ---------------------------------------------------------------------------

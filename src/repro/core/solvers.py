@@ -33,6 +33,16 @@ class Solver:
     # the executor may run it inside lax.fori_loop / lax.scan segments
     scannable: bool = True
 
+    def __post_init__(self):
+        # every path's solver step runs under one trace scope
+        step = self.step
+
+        def scoped(*args, **kwargs):
+            with jax.named_scope("solver"):
+                return step(*args, **kwargs)
+
+        self.step = scoped
+
 
 # ---------------------------------------------------------------------------
 # DDIM (η = 0) on the VP schedule — the paper's DiT-XL protocol

@@ -80,8 +80,9 @@ def init_cache(spec: BlockSpec, d_model: int, batch: int, cache_len: int,
 def _modulation(spec: BlockSpec, params, cond):
     if not spec.adaln:
         return None
-    m = jax.nn.silu(cond) @ params["mod"]["w"] + params["mod"]["b"]
-    return jnp.split(m[:, None, :], 6, axis=-1)  # each (B, 1, d)
+    with jax.named_scope("adaln"):
+        m = jax.nn.silu(cond) @ params["mod"]["w"] + params["mod"]["b"]
+        return jnp.split(m[:, None, :], 6, axis=-1)  # each (B, 1, d)
 
 
 def _mod_norm(x_norm, shift, scale):
@@ -114,51 +115,55 @@ def apply(spec: BlockSpec, params, x, *, mode: str = "full", d_model: int,
             out = branch_cache["mixer"]
             new_cache = cache  # state caches only advance when computed
         else:
-            h = L.apply_norm(spec.norm, params["norm1"], x)
-            if mod is not None:
-                h = _mod_norm(h, mod[0], mod[1])
-            m = spec.mixer
-            if isinstance(m, AttentionSpec):
-                if mode == "full":
-                    out, kv = attention.apply(m, params["mixer"], h,
-                                              positions=positions, mode="full",
-                                              use_flash=use_flash,
-                                              video_shape=video_shape)
-                    new_cache = kv
+            with jax.named_scope(t):
+                h = L.apply_norm(spec.norm, params["norm1"], x)
+                if mod is not None:
+                    h = _mod_norm(h, mod[0], mod[1])
+                m, p = spec.mixer, params["mixer"]
+                if isinstance(m, AttentionSpec):
+                    if mode == "full":
+                        out, kv = attention.apply(
+                            m, p, h, positions=positions, mode="full",
+                            use_flash=use_flash, video_shape=video_shape)
+                        new_cache = kv
+                    else:
+                        out, new_cache = attention.apply(
+                            m, p, h, mode="decode", pos=pos,
+                            cache={k: v for k, v in cache.items()
+                                   if k != "slots"},
+                            slot_pos=cache["slots"])
+                elif isinstance(m, SSMSpec):
+                    if mode == "full":
+                        out, new_cache = ssm.apply_full(
+                            m, p, h, d_model, use_kernel=use_flash)
+                    else:
+                        out, new_cache = ssm.apply_decode(m, p, h, cache,
+                                                          d_model)
                 else:
-                    out, new_cache = attention.apply(
-                        m, params["mixer"], h, mode="decode", pos=pos,
-                        cache={k: v for k, v in cache.items() if k != "slots"},
-                        slot_pos=cache["slots"])
-            elif isinstance(m, SSMSpec):
-                if mode == "full":
-                    out, new_cache = ssm.apply_full(m, params["mixer"], h,
-                                                    d_model, use_kernel=use_flash)
-                else:
-                    out, new_cache = ssm.apply_decode(m, params["mixer"], h,
-                                                      cache, d_model)
-            else:
-                if mode == "full":
-                    out, new_cache = rglru.apply_full(m, params["mixer"], h, d_model)
-                else:
-                    out, new_cache = rglru.apply_decode(m, params["mixer"], h,
-                                                        cache, d_model)
-            if spec.post_norm:
-                out = L.apply_norm(spec.norm, params["post_norm1"], out)
-            branch_out["mixer"] = out
+                    if mode == "full":
+                        out, new_cache = rglru.apply_full(m, p, h, d_model)
+                    else:
+                        out, new_cache = rglru.apply_decode(m, p, h, cache,
+                                                            d_model)
+                if spec.post_norm:
+                    out = L.apply_norm(spec.norm, params["post_norm1"],
+                                       out)
+                branch_out["mixer"] = out
         if mod is not None:
             out = out * mod[2]
         x = x + out.astype(x.dtype)
 
     # ----- cross-attention -----
     if spec.cross is not None:
-        if skip.get(types["cross"], False):
+        t = types["cross"]
+        if skip.get(t, False):
             out = branch_cache["cross"]
         else:
-            h = L.apply_norm(spec.norm, params["norm_x"], x)
-            out, _ = attention.apply(spec.cross, params["cross"], h,
-                                     positions=positions, mode="full",
-                                     memory=memory)
+            with jax.named_scope(t):
+                h = L.apply_norm(spec.norm, params["norm_x"], x)
+                out, _ = attention.apply(spec.cross, params["cross"], h,
+                                         positions=positions, mode="full",
+                                         memory=memory)
             branch_out["cross"] = out
         x = x + out.astype(x.dtype)
 
@@ -168,17 +173,19 @@ def apply(spec: BlockSpec, params, x, *, mode: str = "full", d_model: int,
         if skip.get(t, False):
             out = branch_cache["ffn"]
         else:
-            h = L.apply_norm(spec.norm, params["norm2"], x)
-            if mod is not None:
-                h = _mod_norm(h, mod[3], mod[4])
-            if isinstance(spec.ffn, MoESpec):
-                out, aux = moe.apply(spec.ffn, params["ffn"], h,
-                                     strategy=moe_strategy,
-                                     group_size=moe_group_size)
-            else:
-                out = mlp.apply(spec.ffn, params["ffn"], h)
-            if spec.post_norm:
-                out = L.apply_norm(spec.norm, params["post_norm2"], out)
+            with jax.named_scope(t):
+                h = L.apply_norm(spec.norm, params["norm2"], x)
+                if mod is not None:
+                    h = _mod_norm(h, mod[3], mod[4])
+                if isinstance(spec.ffn, MoESpec):
+                    out, aux = moe.apply(spec.ffn, params["ffn"], h,
+                                         strategy=moe_strategy,
+                                         group_size=moe_group_size)
+                else:
+                    out = mlp.apply(spec.ffn, params["ffn"], h)
+                if spec.post_norm:
+                    out = L.apply_norm(spec.norm, params["post_norm2"],
+                                       out)
             branch_out["ffn"] = out
         if mod is not None:
             out = out * mod[5]

@@ -1,36 +1,98 @@
-"""Span/event tracer → Chrome trace-event JSON (Perfetto-loadable).
+"""Span/event tracer → Chrome trace-event JSON (Perfetto-loadable), and
+spans on the profiler's own timeline.
 
 The serving stack is a scheduler: the interesting questions ("why was
-this batch slow?", "what did the engine do during the overload ramp?")
-are about *intervals* and their nesting, not aggregates.  The tracer
-records them as Chrome trace events — duration spans (``B``/``E``) on
-one track (``tid``) per in-flight batch, instant events (``i``) for
-point occurrences (rung moves, watchdog fires, retries, sheds) — so a
-recorded serve session drops straight into Perfetto / ``chrome://tracing``.
+this batch slow?", "what was the engine doing while the chip idled?")
+are about *intervals* and their nesting, not aggregates.  Spans go to two
+sinks through one call, :meth:`Tracer.span` / :meth:`NullTracer.span`:
 
-Design constraints, in order:
+* a ``jax.profiler.TraceAnnotation`` of the same name and args, so the
+  program's spans land on the profiler's host timeline — the clock the
+  device trace is aligned to.  With no profiler session active this is
+  one inactive TraceMe check;
+* with a recording :class:`Tracer`, a ``B``/``E`` pair on its own clock —
+  one track (``tid``) per in-flight batch, plus instant events (``i``)
+  for point occurrences (rung moves, watchdog fires, retries, sheds) —
+  so a recorded serve session drops straight into Perfetto and
+  virtual-clock traces stay exactly reproducible.
 
-* **~zero cost when disabled.**  Engine code holds a tracer
-  unconditionally; the disabled case is :data:`NULL_TRACER`, whose
-  methods are empty — no conditionals at call sites, no event storage.
-* **Clock-agnostic.**  Anything with a ``now() -> float`` (seconds)
-  works: the serving stack's ``WallClock``/``VirtualClock``, or the
-  default ``time.monotonic`` wrapper.  Virtual-clock traces are exactly
-  reproducible, which is what the overhead benchmark diffs.
-* **Cheap while enabled.**  Recording is one tuple append; all JSON
-  shaping happens at export time.
+Disabled is :data:`NULL_TRACER`: no conditionals at call sites, no event
+storage.  Anything with a ``now() -> float`` (seconds) works as the
+clock: the serving stack's ``WallClock``/``VirtualClock``, or the default
+``time.monotonic`` wrapper.  Recording is one tuple append; all JSON
+shaping happens at export time.
 
 Matched-pair discipline is enforced at record time (``end`` without an
 open span raises) and re-checked structurally by
-:func:`validate_chrome_trace`, which the benchmark runs on the exported
-JSON — monotonic timestamps per track, every ``B`` closed by its ``E``.
+:func:`validate_chrome_trace` — monotonic timestamps per track, every
+``B`` closed by its ``E``.
 """
 from __future__ import annotations
 
 import json
 import time
-from contextlib import contextmanager
 from typing import Any, Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
+
+
+#: a TraceMe encodes ``name#k=v,k=v#`` and reads brackets as nesting, so
+#: string values lose these characters
+_TRACEME_TEXT = str.maketrans({",": ";", "=": ":", "#": " ", "[": None,
+                               "]": None, "(": None, ")": None, "{": None,
+                               "}": None})
+
+
+def _traceme_args(args: Dict[str, Any]) -> Dict[str, Any]:
+    """Args as a TraceMe can carry them: sequences become space-joined
+    strings, and strings lose the characters of its encoding."""
+    out = {}
+    for k, v in args.items():
+        if isinstance(v, (list, tuple)):
+            v = " ".join(map(str, v))
+        if isinstance(v, str):
+            v = v.translate(_TRACEME_TEXT)
+        out[k] = v
+    return out
+
+
+class Span:
+    """One open span in both sinks (see the module docstring).
+    :meth:`update` adds args known only once the work ran: they land on
+    the recording tracer's ``E`` event and on the TraceMe."""
+
+    __slots__ = ("_tracer", "_tid", "_name", "_args", "_end", "_ann")
+
+    def __init__(self, tracer, tid: int, name: str, args: Dict[str, Any]):
+        self._tracer, self._tid, self._name = tracer, tid, name
+        self._args, self._end, self._ann = args, {}, None
+
+    def __enter__(self) -> "Span":
+        if TraceAnnotation.is_enabled():
+            self._ann = TraceAnnotation(self._name,
+                                        **_traceme_args(self._args))
+            self._ann.__enter__()
+        if self._tracer is not None:
+            self._tracer.begin(self._tid, self._name, **self._args)
+        return self
+
+    def update(self, **args) -> None:
+        self._end.update(args)
+        if self._ann is not None:
+            self._ann.set_metadata(**_traceme_args(args))
+
+    def __exit__(self, *exc) -> bool:
+        if self._tracer is not None:
+            self._tracer.end(self._tid, self._name, **self._end)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        return False
+
+
+def profiling() -> bool:
+    """Whether a profiler session would record a span now — lets a hot
+    path skip building args that only a sink would read."""
+    return TraceAnnotation.is_enabled()
 
 
 class _MonotonicClock:
@@ -61,9 +123,9 @@ class NullTracer:
     def instant(self, name: str, tid: int = 0, **args) -> None:
         pass
 
-    @contextmanager
-    def span(self, tid: int, name: str, **args):
-        yield
+    def span(self, tid: int, name: str, **args) -> Span:
+        """A span on the profiler's timeline only."""
+        return Span(None, tid, name, args)
 
     def to_chrome_trace(self) -> Dict[str, Any]:
         return {"traceEvents": []}
@@ -124,13 +186,10 @@ class Tracer:
         self._events.append(("i", self.clock.now(), tid, name,
                              args or None))
 
-    @contextmanager
-    def span(self, tid: int, name: str, **args):
-        self.begin(tid, name, **args)
-        try:
-            yield
-        finally:
-            self.end(tid, name)
+    def span(self, tid: int, name: str, **args) -> Span:
+        """A ``B``/``E`` pair on track ``tid`` and a profiler span; the
+        pair stays matched when the body raises."""
+        return Span(self, tid, name, args)
 
     # -- export --------------------------------------------------------------
 

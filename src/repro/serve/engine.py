@@ -51,7 +51,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.obs import NULL_TRACER, MetricsRegistry, run_cache_reports
+from repro.obs import (NULL_TRACER, MetricsRegistry, profiling,
+                       run_cache_reports)
 from repro.resilience.faults import NAN_LATENT, STUCK_BATCH, BatchFault
 from repro.serve.batcher import MicroBatch, MicroBatcher, bucket_sizes
 from repro.serve.metrics import ServerMetrics
@@ -189,7 +190,6 @@ class ServeEngine:
         self.metrics = ServerMetrics(registry=self.registry)
         self.tracer = tracer if tracer is not None else NULL_TRACER
         if tracer is not None:
-            store.tracer = tracer
             self.batcher.tracer = tracer
         self.telemetry = bool(telemetry)
         self.cache_reports: Dict[int, object] = {}   # rid → CacheReport
@@ -474,32 +474,35 @@ class ServeEngine:
         if any(lab is not None for lab in mb.labels):
             label = jnp.asarray([0 if lab is None else int(lab)
                                  for lab in mb.labels], jnp.int32)
-        if self.eager:
-            kind, rs = "eager", _EagerState()
-        elif entry.adaptive and self._fused_adaptive:
-            kind = "adaptive_fused"
-            if self.telemetry:
-                # decision-trace carry rides the fused program; passed
-                # only when on so executors (and test fakes) without the
-                # kwarg keep working
-                extra["telemetry"] = True
-            rs = self.executor.start_adaptive_fused_run(
-                self.params, key, mb.bucket, schedule=entry.schedule,
-                tau=entry.tau, proxy_map=entry.proxy_map,
-                pool=entry.pool(), k_max=entry.k_max, label=label,
-                **extra)
-        elif entry.adaptive:
-            kind = "adaptive"
-            rs = self.executor.start_adaptive_run(
-                self.params, key, mb.bucket, schedule=entry.schedule,
-                tau=entry.tau, proxy_map=entry.proxy_map,
-                pool=entry.pool(), k_max=entry.k_max, label=label,
-                **extra)
-        else:
-            kind = "plan"
-            rs = self.executor.start_run(
-                self.params, key, mb.bucket, plan=entry.plan,
-                schedule=entry.schedule, label=label, **extra)
+        # ``serve.launch`` is named by the serial the batch is about to get
+        with self.tracer.span(0, "serve.launch", serial=self._serial + 1,
+                              bucket=mb.bucket, rids=list(mb.rids)):
+            if self.eager:
+                kind, rs = "eager", _EagerState()
+            elif entry.adaptive and self._fused_adaptive:
+                kind = "adaptive_fused"
+                if self.telemetry:
+                    # decision-trace carry rides the fused program; passed
+                    # only when on so executors (and test fakes) without the
+                    # kwarg keep working
+                    extra["telemetry"] = True
+                rs = self.executor.start_adaptive_fused_run(
+                    self.params, key, mb.bucket, schedule=entry.schedule,
+                    tau=entry.tau, proxy_map=entry.proxy_map,
+                    pool=entry.pool(), k_max=entry.k_max, label=label,
+                    **extra)
+            elif entry.adaptive:
+                kind = "adaptive"
+                rs = self.executor.start_adaptive_run(
+                    self.params, key, mb.bucket, schedule=entry.schedule,
+                    tau=entry.tau, proxy_map=entry.proxy_map,
+                    pool=entry.pool(), k_max=entry.k_max, label=label,
+                    **extra)
+            else:
+                kind = "plan"
+                rs = self.executor.start_run(
+                    self.params, key, mb.bucket, plan=entry.plan,
+                    schedule=entry.schedule, label=label, **extra)
         for r in mb.requests:
             r.started = now
         serial, track = self._begin_track(
@@ -526,7 +529,45 @@ class ServeEngine:
         return bool(getattr(self.executor, "supports_fused_adaptive",
                             False))
 
+    def _advance_args(self, fl: _Inflight) -> Dict:
+        """Args of a ``serve.advance`` span: the batch, its run kind, the
+        step it starts from and, for a plan, the segment's label."""
+        args = {"serial": fl.serial, "kind": fl.kind}
+        step = getattr(fl.rs, "step", None)
+        if step is not None:
+            args["step_from"] = int(step)
+        if fl.kind == "plan":
+            plan = getattr(fl.rs, "plan", None)
+            ri = getattr(fl.rs, "run_index", None)
+            if plan is not None and ri is not None \
+                    and hasattr(plan, "run_label"):
+                try:
+                    args["segment"] = plan.run_label(int(ri))
+                except (IndexError, TypeError):
+                    pass
+        return args
+
     def _advance(self, fl: _Inflight) -> None:
+        """One advance unit under a ``serve.advance`` span on the batch's
+        track.  ``new_program`` on its end says whether the executor's
+        program table missed (a tick that compiled); the span stays
+        matched when the advance raises (fault injection)."""
+        tr = self.tracer
+        recording = tr.enabled or profiling()
+        args = self._advance_args(fl) if recording else {}
+        programs = (self.executor.compiled_variant_count() if recording
+                    else 0)
+        with tr.span(fl.track, "serve.advance", **args) as span:
+            self._dispatch(fl)
+            if recording:
+                end = {"new_program": int(
+                    self.executor.compiled_variant_count() != programs)}
+                step = getattr(fl.rs, "step", None)
+                if step is not None:
+                    end["step_to"] = int(step)
+                span.update(**end)
+
+    def _dispatch(self, fl: _Inflight) -> None:
         entry = fl.mb.entry
         if fl.kind == "plan":
             fl.rs = self.executor.advance_run(self.params, fl.rs,
@@ -555,37 +596,6 @@ class ServeEngine:
             fl.rs.x = self.executor.sample(
                 self.params, key, fl.mb.bucket, schedule=entry.schedule,
                 label=fl.label)
-
-    def _advance_traced(self, fl: _Inflight) -> None:
-        """``_advance`` under a per-advance span on the batch's track —
-        the try/finally keeps B/E pairs matched even when the advance
-        raises (fault injection), so exported traces always validate."""
-        tr = self.tracer
-        if not tr.enabled or not fl.track:
-            self._advance(fl)
-            return
-        args = {"kind": fl.kind}
-        step = getattr(fl.rs, "step", None)
-        if step is not None:
-            args["step_from"] = int(step)
-        if fl.kind == "plan":
-            plan = getattr(fl.rs, "plan", None)
-            ri = getattr(fl.rs, "run_index", None)
-            if plan is not None and ri is not None \
-                    and hasattr(plan, "run_label"):
-                try:
-                    args["segment"] = plan.run_label(int(ri))
-                except (IndexError, TypeError):
-                    pass
-        tr.begin(fl.track, "advance", **args)
-        try:
-            self._advance(fl)
-        finally:
-            end = {}
-            step = getattr(fl.rs, "step", None)
-            if step is not None:
-                end["step_to"] = int(step)
-            tr.end(fl.track, "advance", **end)
 
     # -- continuous batching (join / regroup / coalesce) ---------------------
 
@@ -916,7 +926,7 @@ class ServeEngine:
         before = self.clock.now()
         steps_before = remaining_steps(fl.rs)
         try:
-            self._advance_traced(fl)
+            self._advance(fl)
         except BatchFault as bf:
             self._inflight.pop(i)
             self._fault_abort(fl, bf.kind, bf.sample_flags,
@@ -993,10 +1003,16 @@ class ServeEngine:
         self.metrics.observe_lineage("split_retry", len(groups))
 
     def _finish(self, fl: _Inflight) -> None:
+        """Deliver a finished batch: ``serve.finish.wait`` spans the
+        ``block_until_ready`` and ``serve.finish.copy`` the host copy; the
+        journal, metrics and cost-model bookkeeping is the self time of
+        the caller's ``serve.finish`` span."""
         mb, rs = fl.mb, fl.rs
-        x = jax.block_until_ready(rs.x)
+        with self.tracer.span(0, "serve.finish.wait"):
+            x = jax.block_until_ready(rs.x)
         done = self.clock.now()
-        x = np.asarray(x)
+        with self.tracer.span(0, "serve.finish.copy"):
+            x = np.asarray(x)
         # service time of the whole batch, snapshotted before any faulted
         # row's re-queue resets its start stamp
         service = done - mb.requests[0].started
@@ -1114,19 +1130,14 @@ class ServeEngine:
                         row_keyed=bool(fl.row_keyed),
                         lineage=list(fl.lineage), t=float(now))
             name, nbytes = self._snapshots.save(fl.serial, arrays, meta)
-        except Exception as e:
+        except Exception:
             self.metrics.observe_checkpoint_error()
-            self.tracer.instant("checkpoint_error", serial=fl.serial,
-                                error=type(e).__name__)
             return
         self.metrics.observe_checkpoint(nbytes)
         step = static.get("step", static.get("run_index", 0))
         self._journal("checkpoint", sync=False, serial=fl.serial,
                       snapshot=name, step=int(step),
                       rids=list(fl.mb.rids), t=float(now))
-        if self.tracer.enabled:
-            self.tracer.instant("checkpoint", tid=fl.track, snapshot=name,
-                                bytes=int(nbytes))
 
     def _rebuild_request(self, rec: Dict) -> Request:
         """Journal submit record → Request, verbatim (original arrival,
@@ -1154,8 +1165,6 @@ class ServeEngine:
         self.store.health.quarantine(f"snapshot:{qname}", reason)
         summary["refused"].append((qname, reason))
         self.metrics.observe_snapshot_refused()
-        self.tracer.instant("snapshot_refused", snapshot=qname,
-                            reason=reason)
 
     def _restore_snapshot(self, path: str, pending: Dict, restored: set,
                           started: Dict, now: float,
@@ -1305,8 +1314,6 @@ class ServeEngine:
                       restored_requests=summary["restored_requests"],
                       replayed=summary["replayed"],
                       refused=len(summary["refused"]), t=float(now))
-        self.tracer.instant("recover", **{
-            k: v for k, v in summary.items() if k != "refused"})
         return summary
 
     def step(self) -> bool:
@@ -1315,43 +1322,48 @@ class ServeEngine:
         scheduling policy selects by one unit (a plan segment / an
         adaptive step-chunk / a whole eager batch).  Returns False when
         nothing is runnable *right now* (requests may still be in flight
-        toward their arrival time)."""
-        now = self.clock.now()
-        self._slo_sweep(now)
-        self._admit(now)
-        if not self._inflight:
-            return False
-        i = self.policy.select(self, now)
-        fl = self._inflight[i]
-        if fl.parked_by is not None:
-            # a parked join target doesn't advance — its timeslice goes
-            # to the chaser catching up to it
-            fl = fl.parked_by
-            i = self._inflight.index(fl)
-        if self.resilience is None:
-            self._advance_traced(fl)
-        elif self._advance_guarded(i, fl):
-            return True                       # batch aborted into recovery
-        if fl.rs.done:
-            self._inflight.pop(i)
-            self._finish(fl)
-        else:
-            if self.continuous:
-                if fl.chaser_for is not None:
-                    self._try_merge(fl)
-                else:
-                    self._maybe_regroup(fl)
-                self._coalesce()
-            if fl in self._inflight:
-                # boundary checkpoint: the host just finished an advance
-                # (plan segment / adaptive chunk) — the only place a
-                # snapshot is ever taken, so the fused path's
-                # host_sync_count stays exactly where it was
-                self._maybe_checkpoint(fl)
-            if fl in self._inflight and self.policy.rotate():
-                self._inflight.remove(fl)
-                self._inflight.append(fl)
-        return True
+        toward their arrival time).  The tick is the ``serve.step`` span;
+        ``serve.admit``, ``serve.launch``, ``serve.advance`` and
+        ``serve.finish`` nest in it."""
+        with self.tracer.span(0, "serve.step"):
+            now = self.clock.now()
+            with self.tracer.span(0, "serve.admit"):
+                self._slo_sweep(now)
+                self._admit(now)
+            if not self._inflight:
+                return False
+            i = self.policy.select(self, now)
+            fl = self._inflight[i]
+            if fl.parked_by is not None:
+                # a parked join target doesn't advance — its timeslice goes
+                # to the chaser catching up to it
+                fl = fl.parked_by
+                i = self._inflight.index(fl)
+            if self.resilience is None:
+                self._advance(fl)
+            elif self._advance_guarded(i, fl):
+                return True                       # batch aborted into recovery
+            if fl.rs.done:
+                self._inflight.pop(i)
+                with self.tracer.span(0, "serve.finish", serial=fl.serial):
+                    self._finish(fl)
+            else:
+                if self.continuous:
+                    if fl.chaser_for is not None:
+                        self._try_merge(fl)
+                    else:
+                        self._maybe_regroup(fl)
+                    self._coalesce()
+                if fl in self._inflight:
+                    # boundary checkpoint: the host just finished an advance
+                    # (plan segment / adaptive chunk) — the only place a
+                    # snapshot is ever taken, so the fused path's
+                    # host_sync_count stays exactly where it was
+                    self._maybe_checkpoint(fl)
+                if fl in self._inflight and self.policy.rotate():
+                    self._inflight.remove(fl)
+                    self._inflight.append(fl)
+            return True
 
     def run_until_drained(self) -> Dict[int, np.ndarray]:
         """Serve until every submitted request has an *outcome* — a
@@ -1397,7 +1409,8 @@ class ServeEngine:
                         f"next_event={t} never becomes schedulable")
                 continue
             last_now = now
-            self.clock.sleep_until(t)
+            with self.tracer.span(0, "serve.sleep"):
+                self.clock.sleep_until(t)
         return self.results
 
     # -- reporting -----------------------------------------------------------
@@ -1433,12 +1446,5 @@ class ServeEngine:
         compiles["xla_programs"] = sum(
             self.executor.xla_program_count(kind)
             for kind in self.MODEL_PROGRAM_KINDS)
-        # export the calibrated per-step cost model as registry gauges so
-        # snapshot()/exposition() carry the admission controller's view
-        snap = self.cost_model.snapshot()
-        if snap["global"] is not None:
-            self.registry.set_gauge("slo.step_cost_s", snap["global"])
-        for g, v in snap["per_group"].items():
-            self.registry.set_gauge("slo.step_cost_s", v, group=g)
         return self.metrics.report(compile_counts=compiles,
                                    program_budget=self.program_budget())

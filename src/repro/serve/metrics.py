@@ -98,6 +98,10 @@ class ServerMetrics:
         reg = self.registry
         reg.observe("serve.queue_wait_s", req.queue_wait)
         reg.observe("serve.service_s", req.service_time)
+        if req.admit_lag is not None:
+            # the part of the queue wait before the engine could see the
+            # request; the rest waited for a slot or batch formation
+            reg.observe("serve.admit_lag_s", req.admit_lag)
         if req.joined_at is not None:
             # joiner-specific wait: a boundary join ends the queue wait
             # at the chaser launch — this distribution is what the join
@@ -479,6 +483,8 @@ class ServerMetrics:
             out["slo"]["goodput_rps"] = (self.good / makespan
                                          if makespan > 0 else float("inf"))
             out["queue_wait_s"] = _dist(self.queue_waits)
+            out["admit_lag_s"] = _dist(
+                self.registry.samples("serve.admit_lag_s"))
             out["service_s"] = _dist(self.service_times)
         if compile_counts is not None:
             out["compiles"] = dict(compile_counts)

@@ -108,6 +108,10 @@ class Request:
     priority: int = 0
     slo: Optional[object] = None              # repro.slo.SLO, if any
     arrival: Optional[float] = None
+    #: ``now`` of the first engine look at the queue at or after the
+    #: arrival: a request arriving while the engine blocks is unseen
+    #: until the block ends
+    seen: Optional[float] = None
     started: Optional[float] = None           # micro-batch launch time
     finished: Optional[float] = None          # result materialized
     joined_at: Optional[float] = None         # boundary join, if any
@@ -117,6 +121,12 @@ class Request:
         if self.started is None or self.arrival is None:
             return None
         return self.started - self.arrival
+
+    @property
+    def admit_lag(self) -> Optional[float]:
+        if self.seen is None or self.arrival is None:
+            return None
+        return self.seen - self.arrival
 
     @property
     def service_time(self) -> Optional[float]:
@@ -176,6 +186,8 @@ class RequestQueue:
     def _absorb(self, now: float) -> None:
         while self._future and self._future[0][0] <= now:
             _, _, req = heapq.heappop(self._future)
+            if req.seen is None:
+                req.seen = now
             group = self._ready.setdefault(req.policy, [])
             group.append(req)
             group.sort(key=lambda r: (-r.priority, r.arrival, r.rid))

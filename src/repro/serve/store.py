@@ -24,7 +24,6 @@ from typing import Dict, List, Optional, Tuple, Union
 
 from repro.cache import registry
 from repro.cache.artifact import CacheArtifact
-from repro.obs import NULL_TRACER
 from repro.cache.policy import AdaptivePolicy, CachePolicy
 from repro.core import calibration as calibration_lib
 from repro.core import plan as plan_lib
@@ -211,10 +210,6 @@ class ArtifactStore:
         #: faults can mark a group unhealthy, which resolve_entry_for
         #: honors — the registry the engine consults before formation
         self.health = health if health is not None else HealthRegistry()
-        #: observability hook (repro.obs.Tracer); the engine installs its
-        #: tracer here so rung moves, hot reloads, and fault reports emit
-        #: instant events no matter which component drives them
-        self.tracer = NULL_TRACER
 
     # -- loading -------------------------------------------------------------
 
@@ -386,12 +381,8 @@ class ArtifactStore:
             # ledger and re-raise for the operator
             self.health.quarantine(
                 name, f"hot-reload rejected: {type(e).__name__}: {e}")
-            self.tracer.instant("hot_reload_rejected", entry=name,
-                                error=type(e).__name__)
             raise
         self._entries[name] = entry
-        self.tracer.instant("hot_reload", entry=name,
-                            version=entry.version)
         # a good swap is a fresh start: clear any quarantine record and
         # reset the entry's fault count / unhealthy flag
         self.health.clear_quarantine(name)
@@ -426,11 +417,6 @@ class ArtifactStore:
         batches resolve the new rung.  Zero compiles, by construction."""
         lad = self.ladder(name)
         lad.active = max(0, min(int(index), len(lad.rung_names) - 1))
-        # the one choke point every rung driver goes through (elastic
-        # controller, operator, tests) — instant-event it here
-        self.tracer.instant("set_rung", ladder=name, rung=lad.active,
-                            tau=lad.taus[lad.active],
-                            entry=lad.rung_names[lad.active])
         return self._entries[lad.rung_names[lad.active]]
 
     def resolve_entry_for(self, group: str, req) -> Optional[ServableEntry]:
@@ -467,10 +453,7 @@ class ArtifactStore:
         registry's threshold and the group is now unservable (the engine
         sheds its traffic with reason ``unhealthy_entry`` until a
         successful :meth:`reload` or ``health.mark_healthy``)."""
-        tripped = self.health.report_fault(group, kind)
-        if tripped:
-            self.tracer.instant("entry_unhealthy", entry=group, kind=kind)
-        return tripped
+        return self.health.report_fault(group, kind)
 
     def degraded_entry_name(self, group: str,
                             level: int) -> Optional[str]:

@@ -90,18 +90,6 @@ class ServiceCostModel:
         """Estimated service seconds for a run of ``num_steps`` steps."""
         return self.per_step(group, bucket) * max(int(num_steps), 0)
 
-    def snapshot(self) -> Dict:
-        """The calibrated state as one JSON-safe dict — what the engine
-        exports into the metrics registry as ``slo.step_cost_s`` gauges
-        (observability of the admission pricing, not just its
-        decisions)."""
-        return {
-            "global": self._global,
-            "per_group": dict(sorted(self._per_group.items())),
-            "per_key": {f"{g}|b{b}": v for (g, b), v in
-                        sorted(self._per_key.items())},
-        }
-
 
 class LoadEstimator:
     """Backlog in seconds from queue depth and in-flight remaining work.

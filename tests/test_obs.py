@@ -127,6 +127,68 @@ def test_tracer_save_roundtrip(tmp_path):
         assert validate_chrome_trace(json.load(f)) == 1
 
 
+def _profiled(tmp_path, body):
+    """Run ``body`` under a profiler session; return the host plane's
+    events as ``{name: stats}``."""
+    import glob
+    import os
+
+    import jax
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    path = max(glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                         recursive=True), key=os.path.getmtime)
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    out.setdefault(e.name, dict(e.stats))
+    return out
+
+
+@pytest.mark.parametrize("recording", [False, True],
+                         ids=["null_tracer", "tracer"])
+def test_span_lands_on_the_profiler_timeline(tmp_path, recording):
+    """One span API, two sinks: both tracers put the span and its args
+    (start args and ``update`` args) on the profiler's host timeline, and
+    a recording tracer also keeps its B/E pair on its own clock."""
+    clock = VirtualClock()
+    tr = Tracer(clock) if recording else NULL_TRACER
+
+    def body():
+        with tr.span(0, "serve.probe", serial=7, segment="seg[0] [0,4)",
+                     rids=[3, 4]) as span:
+            clock.advance(1.0)
+            span.update(new_program=1)
+
+    events = _profiled(tmp_path, body)
+    assert events["serve.probe"] == {"serial": 7, "segment": "seg0 0;4",
+                                     "rids": "3 4", "new_program": 1}
+    if recording:
+        evs = [e for e in tr.to_chrome_trace()["traceEvents"]
+               if e["ph"] in "BE"]
+        assert [(e["ph"], e["ts"]) for e in evs] == [("B", 0.0),
+                                                      ("E", 1e6)]
+        assert evs[0]["args"]["segment"] == "seg[0] [0,4)"
+        assert evs[1]["args"] == {"new_program": 1}
+    else:
+        assert tr.to_chrome_trace() == {"traceEvents": []}
+
+
+def test_span_pair_stays_matched_when_the_body_raises():
+    tr = Tracer(VirtualClock())
+    with pytest.raises(RuntimeError):
+        with tr.span(0, "serve.advance"):
+            raise RuntimeError("fault")
+    assert not tr.open_spans()
+    assert validate_chrome_trace(tr.to_chrome_trace()) == 2
+
+
 # ---------------------------------------------------------------------------
 # MetricsRegistry / TimeSeries
 # ---------------------------------------------------------------------------
@@ -440,8 +502,7 @@ def test_engine_traced_run_validates_and_is_identical(tmp_path):
     eng_on = serve.ServeEngine(_FakeExecutor(clock), params=None,
                                store=store, clock=clock, max_batch=4,
                                tracer=tr)
-    assert eng_on.tracer is tr
-    assert store.tracer is tr and eng_on.batcher.tracer is tr
+    assert eng_on.tracer is tr and eng_on.batcher.tracer is tr
     eng_on.submit(*[serve.Request(rid=i, seed=i, policy="static2",
                                   arrival=0.1 * i) for i in range(5)])
     res_on = eng_on.run_until_drained()
@@ -456,7 +517,9 @@ def test_engine_traced_run_validates_and_is_identical(tmp_path):
     assert validate_chrome_trace(obj) > 0
     evs = obj["traceEvents"]
     names = {e["name"] for e in evs if e["ph"] != "M"}
-    assert {"submit", "form", "run", "advance"} <= names
+    assert {"submit", "form", "run", "serve.advance", "serve.step",
+            "serve.admit", "serve.launch", "serve.finish",
+            "serve.finish.wait", "serve.finish.copy"} <= names
     # one track per launched batch, named by serial
     tracks = [e["args"]["name"] for e in evs if e["ph"] == "M"]
     batch_tracks = [t for t in tracks if t.startswith("batch#")]
@@ -467,9 +530,15 @@ def test_engine_traced_run_validates_and_is_identical(tmp_path):
     assert outcomes and all(o == "done" for o in outcomes)
     # plan advances carry the segment label from ExecutionPlan.run_label
     segs = [e["args"]["segment"] for e in evs
-            if e["ph"] == "B" and e["name"] == "advance"
+            if e["ph"] == "B" and e["name"] == "serve.advance"
             and "segment" in e.get("args", {})]
     assert segs and all(s.startswith("seg[") for s in segs)
+    # the first advance of each program compiles it: new_program marks
+    # exactly the advances that grew the executor's program table
+    ends = [e["args"] for e in evs
+            if e["ph"] == "E" and e["name"] == "serve.advance"]
+    assert sum(a["new_program"] for a in ends) \
+        == eng_on.executor.compiled_variant_count()
     path = tr.save(str(tmp_path / "serve.trace.json"))
     with open(path) as f:
         validate_chrome_trace(json.load(f))
@@ -494,6 +563,66 @@ def test_engine_shed_and_reject_instants():
         == {"duplicate_rid": 1, "no_entry": 1}
 
 
+def test_admit_lag_is_the_time_the_engine_was_blocked():
+    """A request arriving while the engine is inside an advance is seen
+    only when the tick ends: with 1 s per segment on the virtual clock,
+    arrivals at 0.25 and 0.6 are seen at 1.0, the rest at once."""
+    clock = serve.VirtualClock()
+    tr = Tracer(clock)
+    store = serve.ArtifactStore(_FakeCfg(), _FakeSolver(8))
+    store.add_policy("nocache", "none")
+    eng = serve.ServeEngine(_FakeExecutor(clock, step_cost=0.125),
+                            params=None, store=store, clock=clock,
+                            max_batch=4, tracer=tr)
+    arrivals = [0.0, 0.25, 0.6, 2.5]
+    reqs = [serve.Request(rid=i, seed=i, policy="nocache", arrival=a)
+            for i, a in enumerate(arrivals)]
+    eng.submit(*reqs)
+    eng.run_until_drained()
+    assert [r.seen for r in reqs] == [0.0, 1.0, 1.0, 2.5]
+    lag = eng.report()["admit_lag_s"]
+    assert lag["n"] == 4 and lag["max"] == pytest.approx(0.75)
+    assert lag["mean"] == pytest.approx((0.75 + 0.4) / 4)
+    # the idle engine sleeps to the last arrival under a serve.sleep span
+    sleeps = [(e["ph"], e["ts"]) for e in tr.to_chrome_trace()["traceEvents"]
+              if e["name"] == "serve.sleep"]
+    assert sleeps == [("B", 2e6), ("E", 2.5e6)]
+
+
+def test_traced_drain_keeps_makespan_and_exports_the_registry(tmp_path):
+    """A mixed two-policy drain with the tracer on serves the same rows
+    and ends at the same virtual time as with it off; its trace validates
+    from disk, and the registry's snapshot and exposition count the
+    batches it served."""
+    def drain(traced):
+        clock = serve.VirtualClock()
+        store = serve.ArtifactStore(_FakeCfg(), _FakeSolver(8))
+        store.add_policy("static2", "static:n=2")
+        store.add_policy("no_cache", "none")
+        kw = {"tracer": Tracer(clock)} if traced else {}
+        eng = serve.ServeEngine(_FakeExecutor(clock), params=None,
+                                store=store, clock=clock, max_batch=4,
+                                max_inflight=2, **kw)
+        eng.submit(*[serve.Request(
+            rid=i, seed=i, policy="static2" if i % 3 else "no_cache",
+            arrival=0.05 * i) for i in range(48)])
+        return eng, eng.run_until_drained(), clock.now()
+
+    eng_off, res_off, end_off = drain(False)
+    eng_on, res_on, end_on = drain(True)
+    assert end_on == end_off
+    assert sorted(res_on) == sorted(res_off) == list(range(48))
+    for rid in res_on:
+        np.testing.assert_array_equal(res_on[rid], res_off[rid])
+    path = eng_on.tracer.save(str(tmp_path / "drain.trace.json"))
+    with open(path) as f:
+        assert validate_chrome_trace(json.load(f)) > 0
+    snap = eng_on.registry.snapshot()
+    json.dumps(snap)
+    assert snap["counters"]["serve.batches"] == len(eng_on.records)
+    assert "# TYPE serve.batches counter" in eng_on.registry.exposition()
+
+
 def test_engine_run_label_helper():
     sch = _static_schedule(6)
     plan = plan_lib.analyze(sch)
@@ -513,15 +642,72 @@ def test_resilience_policy_deadline_helper():
         none_pol.deadline(1.0)
 
 
-def test_cost_model_snapshot_shapes():
-    from repro.slo.admission import ServiceCostModel
-    m = ServiceCostModel()
-    assert m.snapshot() == {"global": None, "per_group": {},
-                            "per_key": {}}
-    m.observe("g", 2.0, 4, bucket=2)
-    snap = m.snapshot()
-    assert snap["global"] is not None
-    assert "g" in snap["per_group"] and "g|b2" in snap["per_key"]
+# ---------------------------------------------------------------------------
+# Branch scopes in the model's op metadata
+# ---------------------------------------------------------------------------
+
+def _strip_metadata(hlo: str) -> str:
+    """Compiled HLO text without op metadata and the stack-frame tables
+    it indexes."""
+    import re
+    out, tables = [], False
+    for line in hlo.splitlines():
+        if line in ("FileNames", "FunctionNames", "FileLocations",
+                    "StackFrames"):
+            tables = True
+            continue
+        if tables and re.match(r"\d+ ", line):
+            continue
+        tables = False
+        out.append(re.sub(r",? metadata=\{[^}]*\}", "", line))
+    return "\n".join(out)
+
+
+def _compiled_denoise_step():
+    """HLO text of one guided-free smoke DiT-XL denoiser call plus its
+    DDIM step, compiled for the CPU (a fresh function each call, so no
+    trace is reused)."""
+    import jax
+    import jax.numpy as jnp
+    from repro import configs
+    from repro.core import diffusion, solvers
+    cfg = configs.get("dit-xl-256", "smoke")
+    solver = solvers.ddim(4)
+
+    def fn(params, x, t, label):
+        pred, _ = diffusion.apply(cfg, params, x, t, label=label)
+        return solver.step(x, pred, 1, {}, None)[0]
+
+    params = jax.eval_shape(lambda k: diffusion.init_params(k, cfg),
+                            jax.random.PRNGKey(0))
+    x = jax.ShapeDtypeStruct((2,) + tuple(cfg.latent_shape), jnp.float32)
+    t = jax.ShapeDtypeStruct((2,), jnp.float32)
+    label = jax.ShapeDtypeStruct((2,), jnp.int32)
+    return jax.jit(fn).lower(params, x, t, label).compile().as_text()
+
+
+@pytest.fixture(scope="module")
+def denoise_hlo():
+    return _compiled_denoise_step()
+
+
+@pytest.mark.parametrize("scope", ["attn", "ffn", "adaln", "embed", "final",
+                                   "solver"])
+def test_denoiser_ops_carry_their_branch_scope(denoise_hlo, scope):
+    import re
+    names = re.findall(r'op_name="([^"]*)"', denoise_hlo)
+    assert any(scope in n.split("/") for n in names)
+
+
+def test_scopes_change_only_op_metadata(denoise_hlo, monkeypatch):
+    import contextlib
+
+    import jax
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain = _compiled_denoise_step()
+    assert "/attn/" not in plain
+    assert _strip_metadata(plain) == _strip_metadata(denoise_hlo)
 
 
 # ---------------------------------------------------------------------------
@@ -650,11 +836,18 @@ def test_engine_telemetry_and_tracing_bit_identical(small_dit, tmp_path):
 
     eng_on, res_on, ex_on = serve_once(True)
     eng_off, res_off, _ = serve_once(False)
-    assert sorted(res_on) == sorted(res_off) == [0, 1]
+    # the engine's spans live on a profiler session's timeline too
+    jax.profiler.start_trace(str(tmp_path / "profile"))
+    try:
+        _, res_prof, ex_prof = serve_once(False)
+    finally:
+        jax.profiler.stop_trace()
+    assert sorted(res_on) == sorted(res_off) == sorted(res_prof) == [0, 1]
     for rid in res_on:
         np.testing.assert_array_equal(res_on[rid], res_off[rid])
-    # telemetry stayed sync-free on the fused path
-    assert ex_on.host_sync_count == 0
+        np.testing.assert_array_equal(res_prof[rid], res_off[rid])
+    # telemetry and spans stayed sync-free on the fused path
+    assert ex_on.host_sync_count == ex_prof.host_sync_count == 0
     assert not eng_off.cache_reports
     assert sorted(eng_on.cache_reports) == [0, 1]
     rec = eng_on.records[0]
@@ -662,6 +855,7 @@ def test_engine_telemetry_and_tracing_bit_identical(small_dit, tmp_path):
         rep = eng_on.cache_reports[rid]
         assert rep.realized == rec.decisions
         assert rep.tau == tau and rep.proxy is not None
+        assert rep.proxy[0] is None
     # trace validates after the drain (all spans closed)
     assert not eng_on.tracer.open_spans()
     assert validate_chrome_trace(eng_on.tracer.to_chrome_trace()) > 0
