@@ -19,16 +19,20 @@ from repro.kernels import ssd as _ssd
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "window", "softcap", "scale", "block_q", "block_k", "interpret"))
+    "causal", "window", "softcap", "scale", "block_q", "block_k", "out_dtype",
+    "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True,
                     window: Optional[int] = None,
                     softcap: Optional[float] = None,
                     scale: Optional[float] = None,
-                    block_q: int = 128, block_k: int = 128,
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None,
+                    out_dtype=None,
                     interpret: bool = False):
     return _fa.flash_attention(q, k, v, causal=causal, window=window,
                                softcap=softcap, scale=scale, block_q=block_q,
-                               block_k=block_k, interpret=interpret)
+                               block_k=block_k, out_dtype=out_dtype,
+                               interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))

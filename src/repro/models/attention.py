@@ -185,6 +185,22 @@ def _sdpa_chunked(q, k, v, positions, *, causal, window, softcap, scale,
     return out_main
 
 
+#: self-attention over at least this many tokens runs the Pallas kernel on
+#: the TPU: on a v5e it beats ``_sdpa`` at 1024 tokens over 2–16 rows, loses
+#: at 256, and at 512 wins from 8 rows but loses at 2 and 4 (PERF.md)
+FLASH_MIN_TOKENS = 1024
+
+
+def takes_flash_kernel(spec: AttentionSpec, length: int) -> bool:
+    """Whether full self-attention over ``length`` tokens takes the Pallas
+    kernel in ``_sdpa``'s place: on the TPU, non-causal, with no window or
+    soft-cap, at or above ``FLASH_MIN_TOKENS``, and outside a sharded
+    program (GSPMD cannot split the kernel, so it would gather q, k, v)."""
+    return (jax.default_backend() == "tpu" and not shardctx.active()
+            and not spec.cross and not spec.causal and spec.window is None
+            and spec.logit_softcap is None and length >= FLASH_MIN_TOKENS)
+
+
 def _decode_sdpa(spec, q, k, v, bias, *, scale: float):
     """One-token attention on the decode cache layouts.
     q: (B,1,H,dh); k: (B,KV,dh,S); v: (B,KV,S,dh); bias: (B,1,S)."""
@@ -206,12 +222,20 @@ def _decode_sdpa(spec, q, k, v, bias, *, scale: float):
 # GQA forward
 # ---------------------------------------------------------------------------
 
-def _gqa_qkv(spec: AttentionSpec, params, x, memory=None):
+def _gqa_qkv(spec: AttentionSpec, params, x, memory=None, dtype=None):
+    """The q, k, v projections; with ``dtype``, in that dtype throughout:
+    operands and results."""
     b = x.shape[0]
     src = memory if spec.cross else x
-    q = x @ params["wq"]
-    k = src @ params["wk"]
-    v = src @ params["wv"]
+
+    def proj(a, w):
+        if dtype is None:
+            return a @ w
+        return a.astype(dtype) @ w.astype(dtype)
+
+    q = proj(x, params["wq"])
+    k = proj(src, params["wk"])
+    v = proj(src, params["wv"])
     if spec.qkv_bias:
         q = q + params["bq"]
         k = k + params["bk"]
@@ -226,7 +250,14 @@ def _gqa_qkv(spec: AttentionSpec, params, x, memory=None):
 
 
 def _gqa_full(spec: AttentionSpec, params, x, positions, memory=None, use_flash=False):
-    q, k, v = _gqa_qkv(spec, params, x, memory)
+    flash = not spec.cross and (use_flash or takes_flash_kernel(spec, x.shape[1]))
+    # On the TPU, XLA runs float32 matmuls at DEFAULT precision as one
+    # bfloat16 pass, so the kernel takes q, k, v in bfloat16: the operands
+    # `_sdpa`'s einsums get there.  Projecting straight to bfloat16 also has
+    # XLA emit them in the kernel's row layout (a float32 projection comes
+    # out with L minor, and a copy to the row layout follows).
+    mxu = jnp.bfloat16 if flash and jax.default_backend() == "tpu" else None
+    q, k, v = _gqa_qkv(spec, params, x, memory, dtype=mxu)
     if spec.pos_emb == "rope" and not spec.cross:
         q = L.apply_rope(q, positions, spec.rope_theta)
         k = L.apply_rope(k, positions, spec.rope_theta)
@@ -234,10 +265,11 @@ def _gqa_full(spec: AttentionSpec, params, x, positions, memory=None, use_flash=
     k = shardctx.constrain(k, "batch", None, "model", None)
     v = shardctx.constrain(v, "batch", None, "model", None)
     scale = 1.0 / math.sqrt(spec.head_dim)
-    if use_flash and not spec.cross:
+    if flash:
         from repro.kernels import ops as kops
         out = kops.flash_attention(q, k, v, causal=spec.causal, window=spec.window,
-                                   softcap=spec.logit_softcap, scale=scale)
+                                   softcap=spec.logit_softcap, scale=scale,
+                                   out_dtype=x.dtype)
     elif not spec.cross and x.shape[1] > CHUNK_THRESHOLD:
         out = _sdpa_chunked(q, k, v, positions, causal=spec.causal,
                             window=spec.window, softcap=spec.logit_softcap,

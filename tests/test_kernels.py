@@ -66,6 +66,103 @@ def test_flash_attention_masks(causal, window, softcap):
                                rtol=5e-5)
 
 
+def _on_tpu(monkeypatch):
+    """Make the model's selection see a TPU backend."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _dit_attention():
+    from repro.configs import dit_xl
+    return dit_xl._block().mixer         # 16 heads of 72, non-causal
+
+
+@pytest.mark.parametrize("rows", [2, 16])
+@pytest.mark.parametrize("l", [256, 1024])
+def test_flash_attention_served_form(l, rows):
+    """The kernel as the model calls it on the TPU — DiT-XL/2's 16 heads of
+    72, non-causal, blocks chosen from the shape, q, k, v in bfloat16 and a
+    float32 output — matches ``_sdpa`` at the same operand precision: on q,
+    k, v rounded to bfloat16.  The kernel also rounds P to bfloat16 for the
+    MXU (unit roundoff 2^-8), so the tolerance is 2^-9 absolute and
+    relative."""
+    from repro.models import attention as A
+
+    ks = jax.random.split(jax.random.PRNGKey(l + rows), 3)
+    q, k, v = (jax.random.normal(kk, (rows, l, 16, 72)).astype(jnp.bfloat16)
+               for kk in ks)
+    scale = 1.0 / np.sqrt(72)
+    out = flash_attention(q, k, v, causal=False, scale=scale,
+                          out_dtype=jnp.float32, interpret=True)
+    assert out.dtype == jnp.float32
+    q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
+    bias = jnp.zeros((1, l, l), jnp.float32)
+    ref = np.concatenate([      # two rows at a time bounds the score tensor
+        np.asarray(A._sdpa(q[r:r + 2], k[r:r + 2], v[r:r + 2], bias,
+                           softcap=None, scale=scale))
+        for r in range(0, rows, 2)])
+    np.testing.assert_allclose(np.asarray(out), ref, atol=2 ** -9,
+                               rtol=2 ** -9)
+
+
+def test_flash_kernel_selection_rule(monkeypatch):
+    """On the TPU the kernel replaces ``_sdpa`` for non-causal, unwindowed,
+    uncapped, unsharded self-attention at or above FLASH_MIN_TOKENS; any
+    other shape, and any other backend, keeps ``_sdpa``."""
+    import dataclasses
+
+    from jax.sharding import Mesh
+
+    from repro import shardctx
+    from repro.models import attention as A
+
+    spec = _dit_attention()
+    n = A.FLASH_MIN_TOKENS
+    assert n > 256                       # the 256-token cell stays on _sdpa
+    assert not A.takes_flash_kernel(spec, 1024)       # CPU backend
+    _on_tpu(monkeypatch)
+    assert A.takes_flash_kernel(spec, 1024)           # DiT-XL/2 at 512x512
+    assert A.takes_flash_kernel(spec, n)
+    assert not A.takes_flash_kernel(spec, n - 1)
+    assert not A.takes_flash_kernel(spec, 256)
+    for other in (dict(causal=True), dict(cross=True), dict(window=128),
+                  dict(logit_softcap=50.0)):
+        assert not A.takes_flash_kernel(dataclasses.replace(spec, **other),
+                                        1024), other
+    mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("data", "model"))
+    with shardctx.use(mesh):
+        assert not A.takes_flash_kernel(spec, 1024)
+
+
+@pytest.mark.parametrize("backend,use_flash,length,taken", [
+    ("cpu", False, 1024, False),     # today's path off the TPU
+    ("cpu", True, 256, True),        # use_flash still forces the kernel
+    ("tpu", False, 1024, True),      # selected by shape
+    ("tpu", False, 256, False),      # below the threshold
+])
+def test_gqa_full_routes_to_kernel(monkeypatch, backend, use_flash, length,
+                                   taken):
+    from repro.kernels import ops
+    from repro.models import attention as A
+
+    calls = []
+
+    def spy(q, k, v, **kw):
+        calls.append((q.dtype, kw["out_dtype"]))
+        return jnp.zeros(q.shape, kw["out_dtype"])
+
+    monkeypatch.setattr(ops, "flash_attention", spy)
+    if backend == "tpu":
+        _on_tpu(monkeypatch)
+    spec = _dit_attention()
+    params = A.init(jax.random.PRNGKey(0), spec, 1152)
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, length, 1152))
+    A.apply(spec, params, x, use_flash=use_flash)
+    assert bool(calls) == taken
+    if taken:           # bfloat16 operands on the TPU, float32 out
+        mxu = jnp.bfloat16 if backend == "tpu" else jnp.float32
+        assert calls == [(mxu, jnp.float32)]
+
+
 @pytest.mark.parametrize("b,l,h,p,g,n,chunk", [
     (2, 64, 4, 16, 1, 16, 16),
     (1, 96, 8, 32, 2, 32, 32),

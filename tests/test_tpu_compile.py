@@ -7,7 +7,9 @@ topology is described inside a fixture — never at import — so every
 test worker collects the same tests and only the worker given this file
 loads the TPU library.
 """
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -47,11 +49,17 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize("shape", [
-    (8, 256, 16, 72),       # DiT-XL/2: 4 requests under CFG, 256 tokens
-    (8, 216, 24, 64),       # Stable Audio Open: 216 tokens → padding path
-], ids=["dit_xl", "stable_audio"])
+DIT_XL = (8, 256, 16, 72)          # DiT-XL/2: 4 requests under CFG, 256 tokens
+DIT_XL_512 = (16, 1024, 16, 72)    # DiT-XL/2 at 512x512: 8 requests, 1024 tokens
+STABLE_AUDIO = (8, 216, 24, 64)    # Stable Audio Open: 216 tokens
+
+
+@pytest.mark.parametrize("shape,dtype", [
+    (DIT_XL, "float32"), (DIT_XL, "bfloat16"),
+    (DIT_XL_512, "bfloat16"),      # the operands the model serves it
+    (STABLE_AUDIO, "float32"), (STABLE_AUDIO, "bfloat16"),
+], ids=["dit_xl-float32", "dit_xl-bfloat16", "dit_xl_512-bfloat16",
+        "stable_audio-float32", "stable_audio-bfloat16"])
 def test_flash_attention_compiles_natively(one_chip, shape, dtype):
     s = jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=one_chip)
     compiled = ops.flash_attention.lower(s, s, s, causal=False).compile()
@@ -83,3 +91,33 @@ def test_dit_xl_forward_fits_one_chip(one_chip):
              + mem.output_size_in_bytes)
     assert mem.argument_size_in_bytes > 2.5e9     # f32 params at full width
     assert total < V5E_HBM_BYTES
+
+
+def test_dit_xl_512_forward_takes_flash_kernel(one_chip, monkeypatch):
+    """Full-width DiT-XL/2 at 512x512 (1024 tokens), 16 rows as served: on
+    the TPU the model picks the Pallas kernel for self-attention, and the
+    kernel's op carries the ``attn`` scope that the device-time shares read.
+    The model asks ``jax.default_backend()``, which sees the CPU here."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(configs.get("dit-xl-256", "full"),
+                              name="dit-xl-512", latent_shape=(64, 64, 4))
+    params = jax.eval_shape(
+        lambda: diffusion.init_params(jax.random.PRNGKey(0), cfg))
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        params)
+    rows = 16
+    x = jax.ShapeDtypeStruct((rows,) + tuple(cfg.latent_shape), jnp.float32,
+                             sharding=one_chip)
+    t = jax.ShapeDtypeStruct((rows,), jnp.float32, sharding=one_chip)
+    label = jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=one_chip)
+
+    def forward(p, x, t, label):
+        return diffusion.apply(cfg, p, x, t, label=label)
+
+    text = jax.jit(forward).lower(params, x, t, label).compile().as_text()
+    kernels = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert kernels
+    for ln in kernels:
+        op_name = re.search(r'op_name="([^"]*)"', ln)
+        assert op_name and "/attn/" in op_name.group(1), ln[:300]
