@@ -4,8 +4,12 @@ A cell names a configuration (``configs/<config>.json``: the model's
 sizes, its sampler, the engine settings, its reference) and a traffic
 mix (``traffic/<mix>.json``, read by ``traffic.py``).  Its correctness
 limits are in ``checks/<cell>.json`` and each metric is read by
-``metrics/<metric>.py``.  So a later cell, mix or metric is new files and
-new entries; nothing here names one.
+``metrics/<metric>.py``.  The solver is the sampler's by name
+(``solver_of``); what conditions a request, and how, is the reference
+module's (``references/<name>.py``: ``condition``, ``request_args``,
+``calibration_records``, ``cond_args``), and the harness passes its
+records through without reading them.  So a later configuration, cell,
+mix or metric is new files and new entries; nothing here names one.
 
 A run:
 
@@ -27,18 +31,21 @@ A run:
    engine's step, the clock's sleeps and the result readback;
 5. reads the peak device memory, frees the program's state, and compares
    a sample of the served latents, drawn from the seed, with the plain
-   reference run on the same weights, noise and labels, under the skip
-   mask the reference derives by its own calibration; and the served
-   mask with the policy's rule under the reference's error curves;
+   reference run on the same weights, noise and conditioning records,
+   under the skip mask the reference derives by its own calibration; and
+   the served mask with the policy's rule under the reference's error
+   curves;
 6. prints the metrics, the device, and each compared number with its
    limit as the last line of standard output.
 """
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import functools
 import gc
 import importlib.util
+import inspect
 import json
 import math
 import os
@@ -167,23 +174,44 @@ class CompileCounter:
 # The program under test
 # ---------------------------------------------------------------------------
 
+#: how the program states each size a configuration file may give under a
+#: name of its own; a key that names a field of the program's
+#: ``ModelConfig`` is compared with that field
+_PROGRAM_SIZES = {
+    "depth": lambda cfg, blocks: len(blocks),
+    "hidden_size": lambda cfg, blocks: cfg.d_model,
+    "num_heads": lambda cfg, blocks: blocks[0].mixer.num_heads,
+    "head_dim": lambda cfg, blocks: blocks[0].mixer.head_dim,
+    "mlp_hidden": lambda cfg, blocks: blocks[0].ffn.d_ff,
+    "patch_size": lambda cfg, blocks: cfg.patch,
+    "branches": lambda cfg, blocks: list(cfg.layer_types()),
+}
+
+
 def program_config(conf):
-    """The program's configuration, checked against the file's sizes."""
+    """The program's configuration, checked against the sizes the file
+    gives."""
     from repro import configs
     ov = {k: tuple(v) if isinstance(v, list) else v
           for k, v in conf["program"]["overrides"].items()}
     cfg = configs.get(conf["program"]["base"],
                       conf["program"]["variant"]).replace(**ov)
-    m = conf["model"]
     blocks = [b for st in cfg.stages for b in st.unit for _ in
               range(st.repeat)]
-    b = blocks[0]
-    got = {"depth": len(blocks), "hidden_size": cfg.d_model,
-           "num_heads": b.mixer.num_heads, "head_dim": b.mixer.head_dim,
-           "mlp_hidden": b.ffn.d_ff, "patch_size": cfg.patch,
-           "latent_shape": list(cfg.latent_shape),
-           "num_classes": cfg.num_classes}
-    bad = {k: (v, m[k]) for k, v in got.items() if v != m[k]}
+    fields = {f.name for f in dataclasses.fields(cfg)}
+    bad = {}
+    for k, want in conf["model"].items():
+        if k in _PROGRAM_SIZES:
+            got = _PROGRAM_SIZES[k](cfg, blocks)
+        elif k in fields:
+            got = getattr(cfg, k)
+        else:
+            continue
+        if k == "branches":     # the program states the branches' types
+            want = list(dict.fromkeys(b["type"] for b in want))
+        got = list(got) if isinstance(got, tuple) else got
+        if got != want:
+            bad[k] = (got, want)
     if bad:
         raise BenchError(f"program config differs from the file: {bad}")
     return cfg
@@ -207,21 +235,44 @@ def make_params(cell, seed: int, ref):
 
 
 def solver_of(conf):
+    """The program's solver: ``SOLVERS[sampler.solver]`` over
+    ``sampler.steps``, with the sampler's other keys (but ``cfg_scale``) as
+    its keywords; ``beta_start`` and ``beta_end``, where given, make its
+    VP schedule (``sched``) over ``num_train_steps``."""
     from repro.core import diffusion, solvers
-    s = conf["sampler"]
-    if s["solver"] != "ddim":
-        raise BenchError(f"unknown solver {s['solver']!r}")
-    return solvers.ddim(s["steps"], sched=diffusion.vp_schedule(
-        s["num_train_steps"], s["beta_start"], s["beta_end"]),
-        num_train_steps=s["num_train_steps"])
+    kw = dict(conf["sampler"])
+    name, steps = kw.pop("solver"), kw.pop("steps")
+    kw.pop("cfg_scale", None)
+    if name not in solvers.SOLVERS:
+        raise BenchError(f"unknown solver {name!r}; known: "
+                         f"{', '.join(sorted(solvers.SOLVERS))}")
+    if "beta_start" in kw or "beta_end" in kw:
+        kw["sched"] = diffusion.vp_schedule(
+            kw["num_train_steps"], kw.pop("beta_start"), kw.pop("beta_end"))
+    make = solvers.SOLVERS[name]
+    try:
+        inspect.signature(make).bind(steps, **kw)
+    except TypeError as e:
+        raise BenchError(f"solver {name!r} does not take the sampler's "
+                         f"keys {sorted(kw)}: {e}") from None
+    return make(steps, **kw)
 
 
-def calibration_labels(cell):
-    """The calibration batch's labels.  Like the model, the calibration
-    batch is the deployment's, the same for every seed (``MODEL_SEED``)."""
-    rng = traffic_lib.rng_for(reference(cell).MODEL_SEED, "calibration")
-    n = int(cell.mix["calibration_samples"])
-    return rng.integers(0, cell.conf["model"]["num_classes"], size=n)
+def conditioner(cell):
+    """Draws one request's conditioning record from a generator: the
+    reference's ``condition`` for the configuration's model and the mix."""
+    return functools.partial(reference(cell).condition, cell.conf["model"],
+                             cell.mix)
+
+
+def calibration_records(cell):
+    """The calibration batch's conditioning records.  Like the model, the
+    calibration batch is the deployment's, the same for every seed
+    (``MODEL_SEED``)."""
+    ref = reference(cell)
+    rng = traffic_lib.rng_for(ref.MODEL_SEED, "calibration")
+    return ref.calibration_records(cell.conf["model"], cell.mix, rng,
+                                   int(cell.mix["calibration_samples"]))
 
 
 def calibration_key(ref):
@@ -233,7 +284,6 @@ def calibration_key(ref):
 
 def calibrate_artifact(cell, seed: int, out: str, cfg=None, params=None):
     """Calibrate the mix's policy on its samples and save the artifact."""
-    import jax.numpy as jnp
     from repro import cache
     if params is None:
         cfg, params = make_params(cell, seed, reference(cell))
@@ -241,10 +291,10 @@ def calibrate_artifact(cell, seed: int, out: str, cfg=None, params=None):
     pipe = cache.DiffusionPipeline(cfg, solver_of(cell.conf),
                                    cell.mix["policy"],
                                    cfg_scale=s["cfg_scale"])
-    labels = jnp.asarray(calibration_labels(cell), jnp.int32)
+    records = calibration_records(cell)
     key = calibration_key(reference(cell))
-    pipe.calibrate(params, key, int(labels.shape[0]),
-                   cond_args={"label": labels})
+    pipe.calibrate(params, key, len(records),
+                   cond_args=reference(cell).cond_args(records))
     return pipe.save_artifact(out)
 
 
@@ -283,36 +333,46 @@ def new_engine(cell, ex, params, store):
         continuous=e["continuous"])
 
 
+def new_request(cell, rid: int, seed: int, record, arrival: float):
+    """The ``serve.Request`` of a drawn request: the reference turns its
+    conditioning record into the request's keywords."""
+    from repro import serve
+    return serve.Request(rid=rid, seed=seed, policy=POLICY, arrival=arrival,
+                         **reference(cell).request_args(record))
+
+
 def warm_up(cell, ex, params, store, buckets, seed: int) -> None:
     """Serve one batch of each bucket shape, largest first."""
-    from repro import serve
     rng = traffic_lib.rng_for(seed, "warmup")
+    condition = conditioner(cell)
     for b in sorted(buckets, reverse=True):
         eng = new_engine(cell, ex, params, store)
         now = eng.clock.now()
-        eng.submit(*[serve.Request(
-            rid=i, seed=int(rng.integers(0, 1 << 31)), policy=POLICY,
-            label=int(rng.integers(0, cell.conf["model"]["num_classes"])),
-            arrival=now) for i in range(b)])
+        reqs = []
+        for i in range(b):
+            req_seed = int(rng.integers(0, 1 << 31))
+            reqs.append(new_request(cell, i, req_seed, condition(rng), now))
+        eng.submit(*reqs)
         if len(eng.run_until_drained()) != b:
             raise BenchError(f"warm-up of bucket {b} did not finish")
 
 
-def drive(eng, traf, annotate):
+def drive(cell, eng, traf, annotate):
     """The window: the loop of ``ServeEngine.run_until_drained`` on the
     wall clock, fed by the traffic.  Open-loop requests are submitted up
     front with their scheduled arrivals; a backlog is topped up to its
     depth until the window ends, when the requests still queued are taken
     back and the batches in flight run to their end.  Returns ``(t0, t_end,
-    requests)``."""
-    from repro import serve
+    requests, records)``: the requests and their conditioning records, both
+    indexed by rid."""
     clock = eng.clock
     requests: List = []
+    records: List = []
 
-    def make(offset, seed, label):
-        r = serve.Request(rid=len(requests), seed=seed, policy=POLICY,
-                          label=label, arrival=t0 + offset)
+    def make(offset, seed, record):
+        r = new_request(cell, len(requests), seed, record, t0 + offset)
         requests.append(r)
+        records.append(record)
         return r
 
     t0 = clock.now() + LEAD_S
@@ -357,12 +417,12 @@ def drive(eng, traf, annotate):
     if open_window:
         window.__exit__(None, None, None)
         t_end = clock.now()
-    return t0, t_end, requests
+    return t0, t_end, requests, records
 
 
 def _record(r) -> Dict:
     return {"bucket": r.bucket, "rids": list(r.rids), "seeds": list(r.seeds),
-            "labels": list(r.labels), "num_steps": r.num_steps,
+            "num_steps": r.num_steps,
             "compute_fraction": r.compute_fraction,
             "formed_at": r.formed_at, "finished_at": r.finished_at}
 
@@ -384,11 +444,11 @@ def reference_check(cell, seed: int, rows: Dict, *, dtype="float32",
     """The reference's answers on its own weights from the seed:
     ``(latents, curves, skip)``.  Where the mix's policy is calibrated,
     the reference calibrates itself on the deployment's calibration batch
-    (``calibration_labels``, ``calibration_key``), giving its error
+    (``calibration_records``, ``calibration_key``), giving its error
     ``curves`` and the ``skip`` mask
     its rule derives; ``latents`` maps each rid in ``rows`` (rid →
-    ``(batch seeds, row, label)``) to its final latent sampled under that
-    mask, in blocks of rows."""
+    ``(batch seeds, row, conditioning record)``) to its final latent
+    sampled under that mask, in blocks of rows."""
     import jax.numpy as jnp
     ref = reference(cell)
     m, s = cell.conf["model"], cell.conf["sampler"]
@@ -397,10 +457,10 @@ def reference_check(cell, seed: int, rows: Dict, *, dtype="float32",
     curves = skip = None
     if cell.mix["policy"] != "none":
         alpha, k_max = ref.policy_rule(cell.mix["policy"])
-        labels = calibration_labels(cell)
-        noise = ref.key_noise(calibration_key(ref), len(labels),
+        records = calibration_records(cell)
+        noise = ref.key_noise(calibration_key(ref), len(records),
                               m["latent_shape"])
-        curves = ref.error_curves(m, s, params, noise, labels, k_max,
+        curves = ref.error_curves(m, s, params, noise, records, k_max,
                                   dtype=dt, precision=precision)
         skip = ref.smoothcache_schedule(curves, alpha, k_max)
     rids = sorted(rows)
@@ -410,8 +470,8 @@ def reference_check(cell, seed: int, rows: Dict, *, dtype="float32",
         part = rids[i:i + block]
         noise = np.stack([np.asarray(ref.batch_noise(
             rows[r][0], m["latent_shape"]))[rows[r][1]] for r in part])
-        labels = np.asarray([rows[r][2] for r in part], np.int32)
-        want = ref.sample(m, s, params, noise, labels, skip, dtype=dt,
+        records = [rows[r][2] for r in part]
+        want = ref.sample(m, s, params, noise, records, skip, dtype=dt,
                           precision=precision)
         out.update(zip(part, want))
     return out, curves, skip
@@ -476,8 +536,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, t_start: float,
     reference's latents, curves and mask, and the compared numbers."""
     setup: Dict[str, float] = {}
     ref = reference(cell)
-    traf = traffic_lib.Traffic(cell.mix, seed, seconds,
-                               cell.conf["model"]["num_classes"])
+    traf = traffic_lib.Traffic(cell.mix, seed, seconds, conditioner(cell))
     calibrated = cell.mix["policy"] != "none"
     work_dir = tempfile.mkdtemp(prefix="chipbench-")
     artifact = os.path.join(work_dir, "cell.cache.json") if calibrated \
@@ -517,7 +576,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, t_start: float,
             jax.profiler.start_trace(logdir, profiler_options=opts)
         setup["setup_s"] = time.monotonic() - t_start
         counter.active = True
-        t0, t_end, requests = drive(eng, traf, annotate)
+        t0, t_end, requests, conds = drive(cell, eng, traf, annotate)
         counter.active = False
         if trace:
             jax.profiler.stop_trace()
@@ -534,7 +593,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, t_start: float,
         for rec in records:
             for j, rid in enumerate(rec["rids"]):
                 if rid in served:
-                    rows[rid] = (rec["seeds"], j, requests[rid].label)
+                    rows[rid] = (rec["seeds"], j, conds[rid])
         reduced = None
         if trace:
             devices, host = (trace_events(t0, t_end) if trace_events

@@ -1,9 +1,10 @@
 """Plain reference of class-conditional DiT sampling (arXiv:2212.09748).
 
 The benchmark's yardstick for the DiT configurations: the weights it
-serves, the noise a served batch starts from, and a straightforward
-``jax.numpy`` DDIM sampler with classifier-free guidance and an optional
-static SmoothCache skip mask.  It imports nothing of the program under
+serves, the noise a served batch starts from, each request's class label
+(its conditioning record), and a straightforward ``jax.numpy`` DDIM
+sampler with classifier-free guidance and an optional static SmoothCache
+skip mask.  It imports nothing of the program under
 test; it follows the published DiT block with the departures that the
 configuration files list under ``assumed``:
 
@@ -40,10 +41,17 @@ ADALN_STD = 0.02
 EMBED_STD = 0.02
 
 
+def _frozen(v):
+    if isinstance(v, list):
+        return tuple(_frozen(x) for x in v)
+    if isinstance(v, dict):
+        return tuple(sorted((k, _frozen(x)) for k, x in v.items()))
+    return v
+
+
 def _model_key(m):
     """The hashable model numbers the jitted functions specialise on."""
-    return tuple(sorted((k, tuple(v) if isinstance(v, list) else v)
-                        for k, v in m.items()))
+    return _frozen(m)
 
 
 def layout(m):
@@ -169,6 +177,38 @@ def make_weights(m, seed: int, dtype=jnp.float32):
     return _make(_model_key(m), jax.random.fold_in(seed_key(MODEL_SEED), 7),
                  jax.random.fold_in(seed_key(seed), 8),
                  jnp.dtype(dtype).name)
+
+
+# ---------------------------------------------------------------------------
+# Conditioning: one class label per request
+# ---------------------------------------------------------------------------
+
+def _uniform_labels(mix) -> None:
+    if mix.get("labels", "uniform") != "uniform":
+        raise ValueError(f"no label law {mix['labels']!r}")
+
+
+def condition(m, mix, rng) -> int:
+    """One request's conditioning record drawn from ``rng``: a class label,
+    uniform over the model's classes (the mix's ``labels``)."""
+    _uniform_labels(mix)
+    return int(rng.integers(0, m["num_classes"]))
+
+
+def request_args(record) -> dict:
+    """The ``serve.Request`` keywords of a record."""
+    return {"label": int(record)}
+
+
+def calibration_records(m, mix, rng, n: int) -> list:
+    """The records of a calibration batch of ``n`` samples."""
+    _uniform_labels(mix)
+    return rng.integers(0, m["num_classes"], size=n).tolist()
+
+
+def cond_args(records) -> dict:
+    """The program's ``cond_args`` for a batch of records."""
+    return {"label": jnp.asarray(records, jnp.int32)}
 
 
 # ---------------------------------------------------------------------------
@@ -326,12 +366,12 @@ def _branch_shape(m, rows):
     return (m["depth"], rows, n, m["hidden_size"])
 
 
-def sample(m, sampler, params, noise, labels, skip=None, *,
+def sample(m, sampler, params, noise, records, skip=None, *,
            dtype=jnp.float32, precision="highest"):
     """DDIM (η = 0) with classifier-free guidance from ``noise``
-    (R, *latent) for ``labels`` (R,); ``skip`` maps ``"attn"``/``"ffn"`` to
-    a boolean per step (True = reuse the cached branch output).  Returns
-    the final latents as a float32 numpy array."""
+    (R, *latent) for the R labels ``records``; ``skip`` maps
+    ``"attn"``/``"ffn"`` to a boolean per step (True = reuse the cached
+    branch output).  Returns the final latents as a float32 numpy array."""
     mkey = _model_key(m)
     ts, ab, ab_next = ddim_tables(sampler)
     steps = len(ts)
@@ -343,7 +383,7 @@ def sample(m, sampler, params, noise, labels, skip=None, *,
     shape = _branch_shape(m, 2 * x.shape[0])
     cache_attn = jnp.zeros(shape, dtype)
     cache_ffn = jnp.zeros(shape, dtype)
-    labels = jnp.asarray(labels, jnp.int32)
+    labels = jnp.asarray(records, jnp.int32)
     for s in range(steps):
         x, cache_attn, cache_ffn = _step(
             mkey, precision, float(sampler["cfg_scale"]), params, x,
@@ -391,19 +431,19 @@ def _calib_step(mkey, prec, cfg_scale, params, x, t, a, an, labels, null,
     return x, tuple(new_window), tuple(errs)
 
 
-def error_curves(m, sampler, params, noise, labels, k_max, *,
+def error_curves(m, sampler, params, noise, records, k_max, *,
                  dtype=jnp.float32, precision="highest"):
     """The per-type error curves of an uncached guided sampling pass from
-    ``noise`` (R, *latent) for ``labels`` (R,): ``{type: (S, K+1)}`` with
-    entry [s, k] the layer mean, then the row mean, of the relative L1
-    change of the branch output at step s against step s−k (conditioned
-    rows only); NaN where k > s, 0 in column 0."""
+    ``noise`` (R, *latent) for the R labels ``records``:
+    ``{type: (S, K+1)}`` with entry [s, k] the layer mean, then the row
+    mean, of the relative L1 change of the branch output at step s against
+    step s−k (conditioned rows only); NaN where k > s, 0 in column 0."""
     mkey = _model_key(m)
     ts, ab, ab_next = ddim_tables(sampler)
     steps = len(ts)
     params = jax.tree.map(lambda a: a.astype(dtype), params)
     x = jnp.asarray(noise, dtype)
-    labels = jnp.asarray(labels, jnp.int32)
+    labels = jnp.asarray(records, jnp.int32)
     shape = (k_max,) + _branch_shape(m, x.shape[0])
     window = tuple(jnp.zeros(shape, jnp.float32) for _ in TYPES)
     curves = {t: np.full((steps, k_max + 1), np.nan) for t in TYPES}

@@ -52,10 +52,10 @@ def main(argv=None) -> int:
     for i, rate in enumerate(float(r) for r in args.rates.split(",")):
         mix = dict(cell.mix, rate_per_s=rate)
         traf = traffic_lib.Traffic(mix, args.seed + i, args.seconds,
-                                   cell.conf["model"]["num_classes"])
+                                   harness.conditioner(cell))
         eng = harness.new_engine(cell, ex, params, store)
-        t0, t_end, reqs = harness.drive(eng, traf,
-                                            contextlib.nullcontext)
+        t0, t_end, reqs, _ = harness.drive(cell, eng, traf,
+                                           contextlib.nullcontext)
         lat = np.array([r.finished - r.arrival for r in reqs])
         third = max(1, len(lat) // 3)
         print(json.dumps({

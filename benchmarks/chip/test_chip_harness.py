@@ -9,6 +9,7 @@ outside the limit, and that a run with the timed path broken reads
 ``correct`` false.
 """
 import dataclasses
+import hashlib
 import json
 import os
 import subprocess
@@ -22,7 +23,8 @@ import harness
 
 BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(BENCH_DIR))
-CELLS = ("dit-xl-256.smoothcache.poisson", "dit-xl-512.nocache.backlog")
+CELLS = ("dit-xl-256.smoothcache.poisson", "dit-xl-512.nocache.backlog",
+         "dit-xl-256.nocache.poisson")
 
 
 def smoke_cell(workload, max_batch=None):
@@ -150,11 +152,11 @@ def test_every_seed_serves_one_model():
     a, b = (ref.make_weights(m, seed) for seed in (2 ** 33 + 5, 12))
     assert any(not np.array_equal(x, y) for x, y in
                zip(jax.tree.leaves(a), jax.tree.leaves(b)))
-    labels = harness.calibration_labels(cell)
-    noise = ref.key_noise(harness.calibration_key(ref), len(labels),
+    records = harness.calibration_records(cell)
+    noise = ref.key_noise(harness.calibration_key(ref), len(records),
                           m["latent_shape"])
     alpha, k_max = ref.policy_rule(cell.mix["policy"])
-    curves = [ref.error_curves(m, s, w, noise, labels, k_max)
+    curves = [ref.error_curves(m, s, w, noise, records, k_max)
               for w in (a, b)]
     for t in curves[0]:
         np.testing.assert_allclose(curves[0][t], curves[1][t], rtol=1e-4,
@@ -162,7 +164,7 @@ def test_every_seed_serves_one_model():
     skips = [ref.smoothcache_schedule(c, alpha, k_max) for c in curves]
     assert {t: list(v) for t, v in skips[0].items()} == \
         {t: list(v) for t, v in skips[1].items()}
-    x = [np.asarray(ref.sample(m, s, w, noise[:2], labels[:2], skips[0]))
+    x = [np.asarray(ref.sample(m, s, w, noise[:2], records[:2], skips[0]))
          for w in (a, b)]
     np.testing.assert_allclose(x[0], x[1], rtol=1e-4, atol=1e-5)
 
@@ -228,3 +230,84 @@ def test_broken_timed_path_reads_incorrect(workload, fault, monkeypatch):
     assert out["correct"] is False
     assert out["checks"]["latent_rel_err"]["value"] > \
         cell.checks["limits"]["latent_rel_err"]
+
+
+#: the parent's draws of the cells that were there before the harness took
+#: conditioning from the reference, per (cell, seed): the count of
+#: requests due in a 50-s window, a digest of them and of the next 16
+#: draws (offset, request seed, label), the requests the check samples
+#: when all are finished, and the first of them's (request seed, label)
+DRAWS_PINNED = {
+    (CELLS[0], 1): (180, "8df68b4b3db74ba7", [9, 30, 43, 48, 51, 60, 125, 143],
+                    (1634616676, 301)),
+    (CELLS[0], 2 ** 33 + 5): (180, "7a8d22d9cbca6b2b",
+                              [9, 69, 80, 84, 101, 141, 157, 165],
+                              (763905611, 709)),
+    (CELLS[0], 2200000601): (180, "a88690516b7c6b72",
+                             [10, 55, 66, 67, 99, 129, 131, 155],
+                             (620216136, 539)),
+    (CELLS[1], 1): (16, "f7bac63248428b0e", [0, 2, 5, 12], (1122378210, 135)),
+    (CELLS[1], 2 ** 33 + 5): (16, "8191b7d93434d88b", [5, 6, 10, 14],
+                              (329646799, 327)),
+    (CELLS[1], 2200000601): (16, "c5c4d2d90d546c14", [0, 9, 10, 13],
+                             (837357251, 908)),
+}
+#: the parent's calibration batch of the 256 cell and its noise key
+CALIBRATION_PINNED = ([864, 960, 141, 196, 967, 743, 850, 998, 173, 418],
+                      [2572633846, 866171279])
+
+
+def _digest(draws):
+    return hashlib.sha256(json.dumps(
+        [[None if o is None else round(o, 9), s, c] for o, s, c in draws]
+    ).encode()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("workload,seed", sorted(DRAWS_PINNED))
+def test_draws_are_the_parents(workload, seed):
+    import traffic
+    cell = harness.load_cell(workload)
+    traf = traffic.Traffic(cell.mix, seed, 50.0, harness.conditioner(cell))
+    due = traf.initial()
+    more = [(None,) + traf.draw() for _ in range(16)]
+    sample = harness.check_sample(cell, seed, list(range(len(due))))
+    got = (len(due), _digest(due + more), sample, due[sample[0]][1:])
+    assert got == DRAWS_PINNED[workload, seed]
+
+
+def test_calibration_batch_is_the_parents():
+    import jax
+    cell = harness.load_cell(CELLS[0])
+    key = harness.calibration_key(harness.reference(cell))
+    got = (harness.calibration_records(cell),
+           np.asarray(jax.random.key_data(key)).tolist())
+    assert got == CALIBRATION_PINNED
+
+
+def test_run_time_cell_with_another_solver_and_records():
+    # a configuration the harness has never seen, as new files would give
+    # it: rectified flow, and records that carry more than a label
+    cell = smoke_cell(CELLS[2], max_batch=2)
+    cell.name = "dit-smoke.flow.poisson"
+    cell.conf["reference"] = "dit_flow"
+    cell.conf["sampler"] = {"solver": "rectified_flow", "steps": 10,
+                            "cfg_scale": 1.5}
+    cell.mix = dict(cell.mix, priorities=3)
+    keep = {}
+    out = run(cell, keep=keep, trace=True)
+    assert harness.solver_of(cell.conf).name == "rectified_flow"
+    assert out["correct"] is True and out["failed"] == 0
+    assert out["checks"]["latent_rel_err"]["value"] < 1e-4
+    records = [keep["rows"][r][2] for r in sorted(keep["rows"])]
+    assert all(set(r) == {"label", "priority"} for r in records)
+    assert len({r["priority"] for r in records}) > 1
+
+
+def test_unknown_solver_is_refused():
+    cell = smoke_cell(CELLS[0])
+    cell.conf["sampler"]["solver"] = "heun"
+    with pytest.raises(harness.BenchError, match="rectified_flow"):
+        harness.solver_of(cell.conf)
+    cell.conf["sampler"]["solver"] = "rectified_flow"   # takes no betas
+    with pytest.raises(harness.BenchError, match="sched"):
+        harness.solver_of(cell.conf)

@@ -125,7 +125,7 @@ def test_flops_agree_with_the_program_arithmetic(name, latent):
     want = program_flops.model_macs_by_type(cfg, seq)
     got = flops.macs_by_type(conf["model"])
     assert got == pytest.approx(want, rel=1e-12)
-    assert flops.non_block_macs(conf["model"], seq) == pytest.approx(
+    assert flops.non_block_macs(conf["model"]) == pytest.approx(
         program_flops.non_block_macs(cfg, seq), rel=1e-12)
     # a whole guided sample under a schedule with skips
     skip = {"attn": np.arange(50) % 3 == 1, "ffn": np.arange(50) % 2 == 1}
@@ -133,6 +133,64 @@ def test_flops_agree_with_the_program_arithmetic(name, latent):
     tmacs = program_flops.sampler_tmacs(cfg, sch, seq, 1, cfg_scale=1.5)
     assert flops.sample_flops(conf["model"], skip, 50, 1.5) == \
         pytest.approx(2e12 * tmacs, rel=1e-9)
+
+
+#: the parent's operation counts of the DiT configurations, before the
+#: branches came from the file: one row's step with all, only attention
+#: and no branch computed; a guided sample with no skips, with every fourth
+#: FFN step skipped, and unguided
+FLOPS_PINNED = {
+    "dit-xl-256": (236784254976.0, 84581351424.0, 24182784.0,
+                   23678425497600.0, 20025555812352.0, 11839212748800.0),
+    "dit-xl-512": (1048589697024.0, 439778082816.0, 80805888.0,
+                   104858969702400.0, 90247490961408.0, 52429484851200.0),
+}
+
+
+@pytest.mark.parametrize("name", sorted(FLOPS_PINNED))
+def test_flops_of_the_dit_configs_are_the_parents(name):
+    m = _model(name)["model"]
+    every_fourth = {"ffn": [s % 4 == 3 for s in range(50)]}
+    got = (flops.row_step_flops(m), flops.row_step_flops(m, ("attn",)),
+           flops.row_step_flops(m, ()), flops.sample_flops(m, None, 50, 1.5),
+           flops.sample_flops(m, every_fourth, 50, 1.5),
+           flops.sample_flops(m, None, 50, None))
+    assert got == FLOPS_PINNED[name]
+
+
+def test_flops_of_factorised_and_cross_attention_agree_with_the_program():
+    # a video model block: spatial and temporal blocks, each with self
+    # attention, cross attention to a text memory, and an MLP
+    from repro import configs
+    from repro.utils import flops as program_flops
+    cfg = configs.get("opensora-v12", "full")
+    branches = []
+    for tag, over in (("s_", "frame"), ("t_", "time")):
+        branches += [{"type": tag + "attn", "op": "self_attn", "over": over,
+                      "count": 28},
+                     {"type": tag + "xattn", "op": "cross_attn", "count": 28},
+                     {"type": tag + "ffn", "op": "mlp", "count": 28}]
+    m = {"depth": 56, "hidden_size": 1152, "num_heads": 16, "head_dim": 72,
+         "mlp_hidden": 4608, "patch_size": 2, "latent_shape": [16, 32, 32, 4],
+         "memory_len": 300, "memory_dim": cfg.cond_dim, "branches": branches}
+    assert (flops.frames(m), flops.frame_tokens(m)) == (16, 256)
+    seq = flops.tokens(m)
+    want = program_flops.model_macs_by_type(cfg, seq, cond_len=300,
+                                            video_shape=(16, 256))
+    got = flops.macs_by_type(m)
+    assert list(got) == list(cfg.layer_types())
+    assert got == pytest.approx(want, rel=1e-12)
+    assert got["s_attn"] != got["t_attn"]
+    assert flops.non_block_macs(m) == pytest.approx(
+        program_flops.non_block_macs(cfg, seq), rel=1e-12)
+    skip = {"t_ffn": [s % 2 == 1 for s in range(30)]}
+    assert flops.sample_flops(m, skip, 30, 7.0) == pytest.approx(
+        2 * 2 * (30 * (sum(want.values()) + flops.non_block_macs(m))
+                 - 15 * want["t_ffn"]), rel=1e-12)
+
+
+def _label(rng):
+    return int(rng.integers(0, 1000))
 
 
 def _mix(**kw):
@@ -145,9 +203,9 @@ def _mix(**kw):
 @pytest.mark.parametrize("rate", [0.5, 6.0])
 def test_traffic_is_deterministic_per_seed(rate):
     mix = _mix(rate_per_s=rate)
-    a = traffic.Traffic(mix, 2 ** 33 + 7, 30.0, 1000).initial()
-    b = traffic.Traffic(mix, 2 ** 33 + 7, 30.0, 1000).initial()
-    c = traffic.Traffic(mix, 2 ** 33 + 8, 30.0, 1000).initial()
+    a = traffic.Traffic(mix, 2 ** 33 + 7, 30.0, _label).initial()
+    b = traffic.Traffic(mix, 2 ** 33 + 7, 30.0, _label).initial()
+    c = traffic.Traffic(mix, 2 ** 33 + 8, 30.0, _label).initial()
     assert a == b and a != c
     assert all(0 <= lab < 1000 and 0 <= s < 2 ** 31 for _, s, lab in a)
     assert all(0 <= t < 30.0 for t, _, _ in a)
@@ -159,7 +217,7 @@ def test_poisson_arrivals_fill_the_window_at_the_rate(rate):
     # gaps spanning one block each; only their order differs
     k = 8
     offs = [np.array(traffic.Traffic(_mix(rate_per_s=rate), seed, 32.0,
-                                     1000).offsets)
+                                     _label).offsets)
             for seed in range(2 ** 33, 2 ** 33 + 20)]
     n = int(32.0 * rate / k) * k
     assert all(len(o) == n and o[0] == 0 and o[-1] < 32.0 for o in offs)
@@ -177,10 +235,11 @@ def test_poisson_arrivals_fill_the_window_at_the_rate(rate):
 
 def test_backlog_keeps_full_buckets_only():
     t = traffic.Traffic({"policy": "none", "arrivals": "backlog",
-                         "depth": 16}, 5, 10.0, 1000)
+                         "depth": 16}, 5, 10.0, _label)
     assert not t.open_loop and len(t.initial()) == 16
     assert t.buckets(8) == (8,)
-    assert traffic.Traffic(_mix(), 5, 10.0, 1000).buckets(8) == (1, 2, 4, 8)
+    assert traffic.Traffic(_mix(), 5, 10.0, _label).buckets(8) == \
+        (1, 2, 4, 8)
     with pytest.raises(ValueError):
         traffic.Traffic({"policy": "none", "arrivals": "backlog",
-                         "depth": 8}, 5, 10.0, 1000).buckets(8)
+                         "depth": 8}, 5, 10.0, _label).buckets(8)

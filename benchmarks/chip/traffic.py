@@ -16,13 +16,16 @@ asks for and the arrival process:
   the window and the queue is topped up to ``depth`` whenever it falls
   below, until the window ends.
 
-Labels are uniform over the classes.  The same seed gives the same
-requests; the program sees only the generated requests.
+Each request's conditioning record (a class label, a prompt, ...) is
+drawn from the same stream, right after its seed, by the configuration's
+reference (``condition``), which reads the mix's own keys for it; this
+module passes records through without reading them.  The same seed gives
+the same requests; the program sees only the generated requests.
 """
 from __future__ import annotations
 
 import math
-from typing import List, Optional, Tuple
+from typing import Any, Callable, List, Optional, Tuple
 
 import numpy as np
 
@@ -65,13 +68,14 @@ def buckets(max_batch: int) -> Tuple[int, ...]:
 
 
 class Traffic:
-    """The requests of one run: ``(offset or None, request seed, label)``
-    triples, offsets in seconds from the start of the window."""
+    """The requests of one run: ``(offset or None, request seed, record)``
+    triples, offsets in seconds from the start of the window.
+    ``condition(rng)`` draws one request's conditioning record."""
 
     def __init__(self, mix: dict, seed: int, seconds: float,
-                 num_classes: int):
+                 condition: Callable[[np.random.Generator], Any]):
         self.mix = mix
-        self.num_classes = int(num_classes)
+        self.condition = condition
         self.seconds = float(seconds)
         self._req = rng_for(seed, "requests")
         kind = mix["arrivals"]
@@ -91,12 +95,12 @@ class Traffic:
     def open_loop(self) -> bool:
         return self.offsets is not None
 
-    def draw(self) -> Tuple[int, int]:
-        """The next request's (seed, label)."""
-        return (int(self._req.integers(0, 1 << 31)),
-                int(self._req.integers(0, self.num_classes)))
+    def draw(self) -> Tuple[int, Any]:
+        """The next request's (seed, conditioning record)."""
+        seed = int(self._req.integers(0, 1 << 31))
+        return seed, self.condition(self._req)
 
-    def initial(self) -> List[Tuple[Optional[float], int, int]]:
+    def initial(self) -> List[Tuple[Optional[float], int, Any]]:
         """Every request due in the window (open loop), or the backlog
         ready at its start."""
         if self.open_loop:
