@@ -38,7 +38,7 @@ def run():
             x0, label = data.batch_at(0)
             cond = {"label": label}
         else:
-            data = CondLatents(cfg.latent_shape, cfg.cond_dim, 8, 10)
+            data = CondLatents(cfg.latent_shape, cfg.input_memory_dim, 8, 10)
             params, _, _ = common.train_small_dit(cfg, key, steps=80,
                                                   data=data, loss_kind=kind)
             _, memory = data.batch_at(0)

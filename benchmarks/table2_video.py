@@ -29,7 +29,7 @@ def run():
 
     cfg = configs.get("opensora-v12", "smoke")
     key = jax.random.PRNGKey(0)
-    data = CondLatents(cfg.latent_shape, cfg.cond_dim, 8, 8)
+    data = CondLatents(cfg.latent_shape, cfg.input_memory_dim, 8, 8)
     params, _, losses = common.train_small_dit(cfg, key, steps=100,
                                                data=data, loss_kind="rf")
     pipe = cache.DiffusionPipeline(cfg, solvers.rectified_flow(steps),

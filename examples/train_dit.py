@@ -34,7 +34,8 @@ def main():
     if cfg.num_classes:
         data = BlobLatents(cfg.latent_shape, cfg.num_classes, args.batch)
     else:
-        data = CondLatents(cfg.latent_shape, cfg.cond_dim, 8, args.batch)
+        data = CondLatents(cfg.latent_shape, cfg.input_memory_dim, 8,
+                           args.batch)
     print(f"[train_dit] {cfg.name}: {cfg.num_layers} blocks, "
           f"latents {cfg.latent_shape}, {args.steps} steps")
     params, sched, losses = common.train_small_dit(

@@ -125,7 +125,11 @@ class BlockSpec:
     ffn: Optional[FFNSpec] = None
     norm: str = "rmsnorm"                # "rmsnorm" | "layernorm"
     post_norm: bool = False              # gemma2: extra norm after branch
-    adaln: bool = False                  # DiT-style adaLN-zero conditioning
+    # adaLN conditioning: None; "zero" (DiT: the block projects the
+    # condition to its shift/scale/gate); "single" (PixArt / STDiT3: a
+    # learned (6, d) table plus one projection shared by every block)
+    adaln: Optional[str] = None
+    cross_norm: bool = True              # norm before cross-attention
     type_tag: str = ""                   # SmoothCache type prefix ("s_"/"t_")
 
     def branch_names(self) -> Tuple[str, ...]:
@@ -174,7 +178,10 @@ class ModelConfig:
     stages: Tuple[Stage, ...] = ()
     norm: str = "rmsnorm"
     tie_embeddings: bool = False
-    pos_emb: str = "none"                # additive absolute pos emb: "none"|"sinusoidal"
+    # additive absolute pos emb: "none" | "sinusoidal"; a diffusion model
+    # takes a 1-D sin-cos table over its tokens unless "sincos_2d" (a 2-D
+    # table over each frame's patch grid)
+    pos_emb: str = "none"
     max_seq_len: int = 8192
     logit_softcap: Optional[float] = None   # gemma2 final softcap 30.0
     embed_scale: bool = False            # gemma: scale embeddings by sqrt(d)
@@ -189,6 +196,15 @@ class ModelConfig:
     latent_shape: Tuple[int, ...] = ()   # diffusion: per-sample latent shape
     patch: int = 1                       # diffusion image patch size
     cond_dim: int = 0                    # cross-attention memory width
+    # text conditioning (Open-Sora): the caller's memory is ``text_dim``
+    # wide (a T5 output); a caption MLP maps it to ``cond_dim``, and CFG's
+    # unconditional rows take a learned null caption of ``text_len`` rows
+    text_dim: int = 0
+    text_len: int = 0
+    fps: int = 0                         # frame rate embedded into t; 0 = none
+    # a diffusion denoiser's matrix products: a jax.default_matmul_precision
+    # name ("high": three bfloat16 passes on the TPU), None = the default
+    matmul_precision: Optional[str] = None
     num_classes: int = 0                 # label conditioning (DiT-XL)
     # long-context policy for long_500k: "native" (ssm/hybrid) | "swa" | None
     long_context: Optional[str] = None
@@ -199,6 +215,12 @@ class ModelConfig:
     @property
     def num_layers(self) -> int:
         return sum(s.num_layers for s in self.stages)
+
+    @property
+    def input_memory_dim(self) -> int:
+        """The width of the memory a caller passes: the text encoder's
+        where the model has a caption MLP, else the cross-attention's."""
+        return self.text_dim or self.cond_dim
 
     def blocks(self):
         """Iterate (stage_idx, rep_idx, block_idx_in_unit, BlockSpec) in order."""

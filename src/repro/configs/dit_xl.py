@@ -16,7 +16,7 @@ def _block():
         mixer=AttentionSpec(num_heads=16, num_kv_heads=16, head_dim=72,
                             causal=False, pos_emb="none"),
         ffn=MLPSpec(d_ff=4608, activation="gelu_tanh", gated=False),
-        norm="layernorm", adaln=True)
+        norm="layernorm", adaln="zero")
 
 
 def full() -> ModelConfig:

@@ -19,7 +19,7 @@ def _block():
         cross=AttentionSpec(num_heads=24, num_kv_heads=24, head_dim=64,
                             cross=True, causal=False, pos_emb="none"),
         ffn=MLPSpec(d_ff=6144, activation="silu", gated=True),
-        norm="layernorm", adaln=True)
+        norm="layernorm", adaln="zero")
 
 
 def full() -> ModelConfig:

@@ -29,7 +29,10 @@ Three execution paths, in order of increasing ahead-of-time analysis:
   ``jit(fn).lower()`` exposes whole-run FLOPs/bytes for accounting.
 
 Classifier-free guidance doubles the batch ([cond; uncond]) exactly as in
-the paper's DiT-XL protocol; the cache covers both halves.
+the paper's DiT-XL protocol; the cache covers both halves.  The
+unconditional half takes the model's own null condition
+(``diffusion.null_cond``).  ``memory`` is an array or a text memory
+``{"text", "mask"}``; either way its rows ride the run state like labels.
 """
 from __future__ import annotations
 
@@ -462,11 +465,14 @@ class SmoothCacheExecutor:
             x2 = jnp.concatenate([x, x], axis=0)
             t2 = jnp.concatenate([t, t], axis=0)
             lab2 = mem2 = None
+            null_label, null_memory = diffusion.null_cond(cfgm, params,
+                                                          label, memory)
             if label is not None:
-                null = jnp.full_like(label, cfgm.num_classes)
-                lab2 = jnp.concatenate([label, null], axis=0)
+                lab2 = jnp.concatenate([label, null_label], axis=0)
             if memory is not None:
-                mem2 = jnp.concatenate([memory, jnp.zeros_like(memory)], axis=0)
+                mem2 = jax.tree.map(
+                    lambda a, b: jnp.concatenate([a, b], axis=0), memory,
+                    null_memory)
             pred, aux = diffusion.apply(
                 cfgm, params, x2, t2, label=lab2, memory=mem2, skip=skip,
                 branch_caches=branch_caches, collect_branches=collect,

@@ -131,9 +131,16 @@ def dpmpp_3m_sde(num_steps: int, sched=None, num_train_steps: int = 1000,
 # (model predicts v = ε − x₀; integrate x from t=1 (noise) to t=0)
 # ---------------------------------------------------------------------------
 
-def rectified_flow(num_steps: int, num_train_steps: int = 1000) -> Solver:
-    # model times: t ∈ (0, 1] scaled by 1000 as during training
+def rectified_flow(num_steps: int, num_train_steps: int = 1000,
+                   shift: float = 1.0) -> Solver:
+    """Euler over ``num_steps`` steps of the grid t = 1 − i/num_steps,
+    moved by Open-Sora's timestep transform t' = shift·t / (1 + (shift −
+    1)·t) (its ``use_timestep_transform``; Open-Sora v1.2 takes shift =
+    sqrt(H·W / 512²) · sqrt(latent frames)); shift = 1 leaves the grid
+    as it is.  The model sees t' · 1000, as during training."""
     tgrid = jnp.linspace(1.0, 0.0, num_steps + 1)
+    if shift != 1.0:
+        tgrid = shift * tgrid / (1.0 + (shift - 1.0) * tgrid)
 
     def step(x, v, s, state, key=None):
         dt = tgrid[s + 1] - tgrid[s]           # negative

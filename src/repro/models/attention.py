@@ -249,7 +249,8 @@ def _gqa_qkv(spec: AttentionSpec, params, x, memory=None, dtype=None):
     return q, k, v
 
 
-def _gqa_full(spec: AttentionSpec, params, x, positions, memory=None, use_flash=False):
+def _gqa_full(spec: AttentionSpec, params, x, positions, memory=None,
+              use_flash=False, memory_mask=None):
     flash = not spec.cross and (use_flash or takes_flash_kernel(spec, x.shape[1]))
     # On the TPU, XLA runs float32 matmuls at DEFAULT precision as one
     # bfloat16 pass, so the kernel takes q, k, v in bfloat16: the operands
@@ -275,7 +276,11 @@ def _gqa_full(spec: AttentionSpec, params, x, positions, memory=None, use_flash=
                             window=spec.window, softcap=spec.logit_softcap,
                             scale=scale)
     else:
-        if spec.cross:
+        if spec.cross and memory_mask is not None:
+            # key padding: a row's queries read its valid memory rows only
+            bias = jnp.where(memory_mask[:, None, :], 0.0,
+                             NEG_INF).astype(jnp.float32)
+        elif spec.cross:
             bias = jnp.zeros((x.shape[0], x.shape[1], memory.shape[1]),
                              jnp.float32)
         else:
@@ -432,10 +437,11 @@ def _mla_decode(spec: AttentionSpec, params, x, pos, cache, slot_pos):
 # ---------------------------------------------------------------------------
 
 def apply(spec: AttentionSpec, params, x, *, positions=None, mode: str = "full",
-          pos=None, cache=None, slot_pos=None, memory=None,
+          pos=None, cache=None, slot_pos=None, memory=None, memory_mask=None,
           use_flash: bool = False, video_shape=None):
     """Returns (out, aux) where aux is a prefill (k, v)/(ckv, krope) tuple in
-    full mode and the updated cache dict in decode mode.
+    full mode and the updated cache dict in decode mode.  ``memory_mask``
+    (B, M) bool: the memory rows cross-attention reads (all when None).
 
     ``video_shape=(T, S)`` + ``spec.pattern`` enables factorized video
     attention: "spatial" attends within each frame (B·T, S), "temporal"
@@ -465,7 +471,8 @@ def apply(spec: AttentionSpec, params, x, *, positions=None, mode: str = "full",
             positions = jnp.arange(x.shape[1])[None, :]
         if spec.kind == "mla":
             return _mla_full(spec, params, x, positions)
-        return _gqa_full(spec, params, x, positions, memory=memory, use_flash=use_flash)
+        return _gqa_full(spec, params, x, positions, memory=memory,
+                         use_flash=use_flash, memory_mask=memory_mask)
     assert mode == "decode"
     if spec.kind == "mla":
         return _mla_decode(spec, params, x, pos, cache, slot_pos)

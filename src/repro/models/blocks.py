@@ -10,6 +10,7 @@ output is used and the branch computation is absent from the traced graph.
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Optional
 
 import jax
@@ -38,7 +39,8 @@ def init(key, spec: BlockSpec, d_model: int, dtype=jnp.float32,
         if spec.post_norm:
             p["post_norm1"] = L.norm_init(spec.norm, d_model, dtype)
     if spec.cross is not None:
-        p["norm_x"] = L.norm_init(spec.norm, d_model, dtype)
+        if spec.cross_norm:
+            p["norm_x"] = L.norm_init(spec.norm, d_model, dtype)
         p["cross"] = attention.init(ks[1], spec.cross, d_model, dtype,
                                     cond_dim=cond_dim)
     if spec.ffn is not None:
@@ -49,7 +51,10 @@ def init(key, spec: BlockSpec, d_model: int, dtype=jnp.float32,
             p["ffn"] = mlp.init(ks[2], spec.ffn, d_model, dtype)
         if spec.post_norm:
             p["post_norm2"] = L.norm_init(spec.norm, d_model, dtype)
-    if spec.adaln:
+    if spec.adaln == "single":
+        # adaLN-single: a table added to the shared 6*d projection of cond
+        p["mod"] = {"table": L.zeros((6, d_model), dtype)}
+    elif spec.adaln == "zero":
         # adaLN-zero: cond → 6*d (shift/scale/gate for mixer and ffn)
         p["mod"] = {"w": L.zeros((adaln_dim, 6 * d_model), dtype),
                     "b": L.zeros((6 * d_model,), dtype)}
@@ -78,9 +83,14 @@ def init_cache(spec: BlockSpec, d_model: int, batch: int, cache_len: int,
 # ---------------------------------------------------------------------------
 
 def _modulation(spec: BlockSpec, params, cond):
+    """The six (B, 1, d) shift/scale/gate rows; with adaLN-single ``cond``
+    is already the shared (B, 6·d) projection."""
     if not spec.adaln:
         return None
     with jax.named_scope("adaln"):
+        if spec.adaln == "single":
+            m = params["mod"]["table"] + cond.reshape(cond.shape[0], 6, -1)
+            return jnp.split(m, 6, axis=1)
         m = jax.nn.silu(cond) @ params["mod"]["w"] + params["mod"]["b"]
         return jnp.split(m[:, None, :], 6, axis=-1)  # each (B, 1, d)
 
@@ -89,17 +99,27 @@ def _mod_norm(x_norm, shift, scale):
     return x_norm * (1.0 + scale) + shift
 
 
-def apply(spec: BlockSpec, params, x, *, mode: str = "full", d_model: int,
-          positions=None, pos=None, cache=None, memory=None, cond=None,
-          skip=None, branch_cache=None, use_flash: bool = False,
-          moe_group_size: int = 2048, moe_strategy: str = "gshard",
-          video_shape=None):
+def apply(spec: BlockSpec, params, x, **kw):
     """Returns (x, branch_out, new_state_cache, aux_loss).
 
     branch_out: dict of pre-residual branch outputs (the SmoothCache cache
     content).  new_state_cache: updated decode cache (or prefill cache in
     full mode).  aux_loss: scalar (MoE load-balance), 0 when absent.
+    ``memory_mask`` (B, M) bool marks the memory rows cross-attention
+    reads.  The ops of a block with a ``type_tag`` run under a scope named
+    for the tag (``s``, ``t``), each branch under its untagged type
+    (``attn``, ``xattn``, ``ffn``) inside it.
     """
+    tag = spec.type_tag.rstrip("_")
+    with jax.named_scope(tag) if tag else contextlib.nullcontext():
+        return _apply(spec, params, x, **kw)
+
+
+def _apply(spec: BlockSpec, params, x, *, mode: str = "full", d_model: int,
+           positions=None, pos=None, cache=None, memory=None,
+           memory_mask=None, cond=None, skip=None, branch_cache=None,
+           use_flash: bool = False, moe_group_size: int = 2048,
+           moe_strategy: str = "gshard", video_shape=None):
     skip = skip or {}
     branch_cache = branch_cache or {}
     mod = _modulation(spec, params, cond)
@@ -107,6 +127,7 @@ def apply(spec: BlockSpec, params, x, *, mode: str = "full", d_model: int,
     new_cache = None
     aux = jnp.zeros((), jnp.float32)
     types = dict(zip(spec.branch_names(), spec.branch_types()))
+    scopes = {t: t[len(spec.type_tag):] for t in types.values()}
 
     # ----- mixer -----
     if spec.mixer is not None:
@@ -115,7 +136,7 @@ def apply(spec: BlockSpec, params, x, *, mode: str = "full", d_model: int,
             out = branch_cache["mixer"]
             new_cache = cache  # state caches only advance when computed
         else:
-            with jax.named_scope(t):
+            with jax.named_scope(scopes[t]):
                 h = L.apply_norm(spec.norm, params["norm1"], x)
                 if mod is not None:
                     h = _mod_norm(h, mod[0], mod[1])
@@ -159,11 +180,13 @@ def apply(spec: BlockSpec, params, x, *, mode: str = "full", d_model: int,
         if skip.get(t, False):
             out = branch_cache["cross"]
         else:
-            with jax.named_scope(t):
-                h = L.apply_norm(spec.norm, params["norm_x"], x)
+            with jax.named_scope(scopes[t]):
+                h = (L.apply_norm(spec.norm, params["norm_x"], x)
+                     if spec.cross_norm else x)
                 out, _ = attention.apply(spec.cross, params["cross"], h,
                                          positions=positions, mode="full",
-                                         memory=memory)
+                                         memory=memory,
+                                         memory_mask=memory_mask)
             branch_out["cross"] = out
         x = x + out.astype(x.dtype)
 
@@ -173,7 +196,7 @@ def apply(spec: BlockSpec, params, x, *, mode: str = "full", d_model: int,
         if skip.get(t, False):
             out = branch_cache["ffn"]
         else:
-            with jax.named_scope(t):
+            with jax.named_scope(scopes[t]):
                 h = L.apply_norm(spec.norm, params["norm2"], x)
                 if mod is not None:
                     h = _mod_norm(h, mod[3], mod[4])

@@ -176,7 +176,7 @@ def _normalize_collect(collect_branches):
 def _unit_apply(stage: Stage, unit_params, x, *, mode, d_model, positions,
                 pos, unit_cache, memory, cond, skip, unit_branch_cache,
                 use_flash, moe_group_size, moe_strategy, collect,
-                video_shape=None):
+                video_shape=None, memory_mask=None):
     branch_outs = []
     new_caches = []
     aux = jnp.zeros((), jnp.float32)
@@ -186,7 +186,8 @@ def _unit_apply(stage: Stage, unit_params, x, *, mode, d_model, positions,
         x, bo, nc, a = blocks.apply(
             b, unit_params[i], x, mode=mode, d_model=d_model,
             positions=positions, pos=pos, cache=cache, memory=memory,
-            cond=cond, skip=skip, branch_cache=bc, use_flash=use_flash,
+            memory_mask=memory_mask, cond=cond, skip=skip, branch_cache=bc,
+            use_flash=use_flash,
             moe_group_size=moe_group_size, moe_strategy=moe_strategy,
             video_shape=video_shape)
         if collect is None:
@@ -204,7 +205,8 @@ def apply_stages(cfg: ModelConfig, params, x, *, mode="full", positions=None,
                  pos=None, caches=None, memory=None, cond=None, skip=None,
                  branch_caches=None, use_flash=False, moe_group_size=2048,
                  moe_strategy="gshard", collect_branches=False,
-                 collect_caches=False, remat=False, video_shape=None):
+                 collect_caches=False, remat=False, video_shape=None,
+                 memory_mask=None):
     """Run all stages. Returns (x, branch_outs, new_caches, aux).
 
     ``collect_branches``: ``True`` collects every branch output, a
@@ -228,7 +230,7 @@ def apply_stages(cfg: ModelConfig, params, x, *, mode="full", positions=None,
                 cond=cond, skip=skip, unit_branch_cache=ubc,
                 use_flash=use_flash, moe_group_size=moe_group_size,
                 moe_strategy=moe_strategy, collect=collect,
-                video_shape=video_shape)
+                video_shape=video_shape, memory_mask=memory_mask)
             ys = {}
             if collect_any:
                 ys["branch"] = bo
@@ -261,7 +263,7 @@ def forward(cfg: ModelConfig, params, tokens=None, *, embeds=None,
             branch_caches=None, use_flash=False, moe_group_size=2048,
             moe_strategy="gshard", collect_branches=False,
             collect_caches=False, remat=False, positions=None,
-            video_shape=None):
+            video_shape=None, memory_mask=None):
     """Full-sequence forward.  For LM: tokens → logits.  For diffusion /
     embedding input: pass ``embeds`` (B, L, d) and get hidden states back
     (the diffusion wrapper owns patchify/head)."""
@@ -276,7 +278,8 @@ def forward(cfg: ModelConfig, params, tokens=None, *, embeds=None,
         cond=cond, skip=skip, branch_caches=branch_caches,
         use_flash=use_flash, moe_group_size=moe_group_size,
         moe_strategy=moe_strategy, collect_branches=collect_branches,
-        collect_caches=collect_caches, remat=remat, video_shape=video_shape)
+        collect_caches=collect_caches, remat=remat, video_shape=video_shape,
+        memory_mask=memory_mask)
     x = L.apply_norm(cfg.norm, params["final_norm"], x)
     if cfg.task == "lm":
         out = logits_from_hidden(cfg, params, x)

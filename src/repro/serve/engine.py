@@ -105,9 +105,11 @@ class BatchRecord:
 
 class _EagerState:
     """Run-state stand-in for the ``--eager`` escape hatch (whole batch
-    sampled in one advance; no interleaving)."""
+    sampled in one advance; no interleaving); it holds the batch's
+    conditioning, which the other run kinds carry in their run state."""
 
-    def __init__(self):
+    def __init__(self, cond: Dict):
+        self.cond = cond
         self.x = None
         self.decisions = None
 
@@ -121,7 +123,6 @@ class _Inflight:
     mb: MicroBatch
     kind: str                                 # "plan" | "adaptive" | "eager"
     rs: object
-    label: object
     #: per-row health known so far (np bool, True = healthy); None = all
     #: healthy.  Monotone: a poisoned row never recovers mid-run.
     taint: object = None
@@ -276,8 +277,19 @@ class ServeEngine:
         and a duplicate rid — against *every* rid ever submitted (queued,
         in flight, done, or earlier in this very call), since a duplicate
         would silently overwrite its sibling's result — is dropped and
-        counted, leaving the original request's outcome untouched."""
+        counted, leaving the original request's outcome untouched.  The
+        one exception: with a journal, a request it cannot hold whole
+        (a text memory) raises ``ValueError`` before any of the call's
+        requests is taken."""
         now = self.clock.now()
+        if self.journal is not None:
+            for r in reqs:
+                if r.memory is not None:
+                    raise ValueError(
+                        f"request {r.rid} carries a text memory, which the "
+                        "journal does not hold: a replay would serve it "
+                        "unconditioned; serve text-conditioned requests "
+                        "without a journal")
         accepted = []
         recs = []
         for r in reqs:
@@ -457,6 +469,36 @@ class ServeEngine:
             self.tracer.begin(track, "run", **args)
         return serial, track
 
+    def _batch_cond(self, mb: MicroBatch) -> Dict:
+        """The batch's conditioning as the executor takes it, stacked in
+        row order and put on the device under a ``serve.cond`` span with
+        its byte count: ``label`` (B,) int32 where a request has one (0
+        stands in for the others), and ``memory``, a text memory
+        ``{"text": (B, M, width), "mask": (B, M) bool}`` whose mask marks
+        each request's first ``memory_len`` rows, where requests carry
+        one."""
+        reqs = mb.requests
+        with self.tracer.span(0, "serve.cond") as span:
+            host = {}
+            if any(r.label is not None for r in reqs):
+                host["label"] = np.asarray(
+                    [0 if r.label is None else int(r.label) for r in reqs],
+                    np.int32)
+            if any(r.memory is not None for r in reqs):
+                if any(r.memory is None for r in reqs):
+                    raise ValueError(
+                        f"batch {list(mb.rids)} mixes requests with and "
+                        "without a text memory")
+                text = np.stack([np.asarray(r.memory, np.float32)
+                                 for r in reqs])
+                lens = np.asarray([text.shape[1] if r.memory_len is None
+                                   else int(r.memory_len) for r in reqs])
+                host["memory"] = {
+                    "text": text,
+                    "mask": np.arange(text.shape[1])[None] < lens[:, None]}
+            span.update(bytes=sum(a.nbytes for a in jax.tree.leaves(host)))
+            return jax.tree.map(jnp.asarray, host)
+
     def _launch(self, mb: MicroBatch, now: float, *,
                 chaser_for=None) -> _Inflight:
         entry = mb.entry
@@ -470,15 +512,12 @@ class ServeEngine:
             # request's bits and replay is per-request
             extra["row_keys"] = [batch_key([s]) for s in mb.seeds]
             row_keyed = True
-        label = None
-        if any(lab is not None for lab in mb.labels):
-            label = jnp.asarray([0 if lab is None else int(lab)
-                                 for lab in mb.labels], jnp.int32)
         # ``serve.launch`` is named by the serial the batch is about to get
         with self.tracer.span(0, "serve.launch", serial=self._serial + 1,
                               bucket=mb.bucket, rids=list(mb.rids)):
+            cond = self._batch_cond(mb)
             if self.eager:
-                kind, rs = "eager", _EagerState()
+                kind, rs = "eager", _EagerState(cond)
             elif entry.adaptive and self._fused_adaptive:
                 kind = "adaptive_fused"
                 if self.telemetry:
@@ -489,27 +528,27 @@ class ServeEngine:
                 rs = self.executor.start_adaptive_fused_run(
                     self.params, key, mb.bucket, schedule=entry.schedule,
                     tau=entry.tau, proxy_map=entry.proxy_map,
-                    pool=entry.pool(), k_max=entry.k_max, label=label,
+                    pool=entry.pool(), k_max=entry.k_max, **cond,
                     **extra)
             elif entry.adaptive:
                 kind = "adaptive"
                 rs = self.executor.start_adaptive_run(
                     self.params, key, mb.bucket, schedule=entry.schedule,
                     tau=entry.tau, proxy_map=entry.proxy_map,
-                    pool=entry.pool(), k_max=entry.k_max, label=label,
+                    pool=entry.pool(), k_max=entry.k_max, **cond,
                     **extra)
             else:
                 kind = "plan"
                 rs = self.executor.start_run(
                     self.params, key, mb.bucket, plan=entry.plan,
-                    schedule=entry.schedule, label=label, **extra)
+                    schedule=entry.schedule, **cond, **extra)
         for r in mb.requests:
             r.started = now
         serial, track = self._begin_track(
             mb, kind,
             chaser_for=chaser_for.serial if chaser_for is not None
             else None)
-        fl = _Inflight(mb=mb, kind=kind, rs=rs, label=label,
+        fl = _Inflight(mb=mb, kind=kind, rs=rs,
                        row_keyed=row_keyed, chaser_for=chaser_for,
                        track=track, serial=serial)
         self._inflight.append(fl)
@@ -595,7 +634,7 @@ class ServeEngine:
             key = batch_key(fl.mb.seeds)
             fl.rs.x = self.executor.sample(
                 self.params, key, fl.mb.bucket, schedule=entry.schedule,
-                label=fl.label)
+                **fl.rs.cond)
 
     # -- continuous batching (join / regroup / coalesce) ---------------------
 
@@ -684,10 +723,6 @@ class ServeEngine:
             tb = (b.taint if b.taint is not None
                   else np.ones(b.mb.bucket, bool))
             taint = np.concatenate([ta, tb])
-        label = None
-        if any(lab is not None for lab in mb.labels):
-            label = jnp.asarray([0 if lab is None else int(lab)
-                                 for lab in mb.labels], jnp.int32)
         rids = ",".join(str(r) for r in b.mb.rids)
         # the merged run keeps a's track/serial — in the trace, b's span
         # ends here with a "merged into a" outcome (a span link by serial)
@@ -699,7 +734,7 @@ class ServeEngine:
         # check guards the window in between
         self._drop_snapshot(b)
         merged = _Inflight(
-            mb=mb, kind=a.kind, rs=merged_rs, label=label, taint=taint,
+            mb=mb, kind=a.kind, rs=merged_rs, taint=taint,
             cost_excluded=a.cost_excluded or b.cost_excluded,
             row_keyed=True,
             lineage=a.lineage + b.lineage
@@ -756,7 +791,7 @@ class ServeEngine:
                                               parent=fl.serial,
                                               via="regroup")
             repl.append(_Inflight(
-                mb=mb, kind=fl.kind, rs=sub, label=fl.label,
+                mb=mb, kind=fl.kind, rs=sub,
                 taint=(None if fl.taint is None
                        else fl.taint[np.asarray(g)]),
                 cost_excluded=fl.cost_excluded, row_keyed=True,
@@ -992,7 +1027,7 @@ class ServeEngine:
                                               parent=fl.serial,
                                               via="split_retry")
             self._inflight.append(_Inflight(
-                mb=mb, kind=fl.kind, rs=sub, label=fl.label, taint=None,
+                mb=mb, kind=fl.kind, rs=sub, taint=None,
                 cost_excluded=fl.cost_excluded, row_keyed=fl.row_keyed,
                 lineage=fl.lineage
                 + (f"split_retry@{fl.rs.step}:{rids}",),
@@ -1222,14 +1257,10 @@ class ServeEngine:
             reqs.append(req)
         mb = MicroBatch(requests=tuple(reqs), entry=entry,
                         formed_at=float(meta.get("formed_at", now)))
-        label = None
-        if any(lab is not None for lab in mb.labels):
-            label = jnp.asarray([0 if lab is None else int(lab)
-                                 for lab in mb.labels], jnp.int32)
         serial, track = self._begin_track(mb, kind, via="restore")
         static = meta.get("static", {})
         at = int(static.get("step", static.get("run_index", 0)))
-        fl = _Inflight(mb=mb, kind=kind, rs=rs, label=label,
+        fl = _Inflight(mb=mb, kind=kind, rs=rs,
                        row_keyed=bool(meta.get("row_keyed", False)),
                        lineage=tuple(meta.get("lineage", ()))
                        + (f"restore@{at}",),

@@ -100,11 +100,15 @@ class Request:
     the queue at submit time unless given explicitly (virtual-clock tests
     and replayed traces pass it).  ``slo`` optionally attaches a
     :class:`repro.slo.SLO` (deadline / quality floor / class label) —
-    requests without one serve exactly as before."""
+    requests without one serve exactly as before.  ``memory`` is a text
+    conditioning memory (M, width), say a T5 output padded to M rows, of
+    which the first ``memory_len`` (all when None) are the caption."""
     rid: int
     seed: int
     policy: str
     label: Optional[int] = None
+    memory: Optional[object] = None           # (M, width) array
+    memory_len: Optional[int] = None
     priority: int = 0
     slo: Optional[object] = None              # repro.slo.SLO, if any
     arrival: Optional[float] = None

@@ -121,3 +121,34 @@ def test_dit_xl_512_forward_takes_flash_kernel(one_chip, monkeypatch):
     for ln in kernels:
         op_name = re.search(r'op_name="([^"]*)"', ln)
         assert op_name and "/attn/" in op_name.group(1), ln[:300]
+
+
+def test_stdit3_forward_fits_one_chip(one_chip):
+    """Full-width STDiT3-XL/2 (Open-Sora v1.2) denoiser forward at the
+    served 240p shape: 4 rows (2 requests under CFG) of 15 frames × 405
+    tokens = 6,075 tokens, reading a 300-row T5 memory under its mask."""
+    cfg = configs.get("opensora-v12", "full")
+    params = jax.eval_shape(
+        lambda: diffusion.init_params(jax.random.PRNGKey(0), cfg))
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        params)
+    rows = 4
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    x = spec((rows,) + tuple(cfg.latent_shape))
+    memory = {"text": spec((rows, cfg.text_len, cfg.text_dim)),
+              "mask": spec((rows, cfg.text_len), jnp.bool_)}
+
+    def forward(p, x, t, memory):
+        return diffusion.apply(cfg, p, x, t, memory=memory)[0]
+
+    assert diffusion.token_shape(cfg)[0] == 6075
+    compiled = jax.jit(forward).lower(params, x, spec((rows,)),
+                                      memory).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.output_size_in_bytes)
+    assert mem.argument_size_in_bytes > 4.5e9     # f32 params at full width
+    assert total < V5E_HBM_BYTES
