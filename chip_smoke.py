@@ -226,7 +226,9 @@ def serve_scenario(check: Checks, dev, name, cfg, params, store, replay):
     if name == "static":
         check(rep["compute_fraction"] < 1.0,
               f"{name}: the schedule skips branch evaluations")
-    rec = eng.records[0]
+    # replayed on the weights the engine serves (on the TPU, its matrices
+    # rounded to bfloat16 once: ``diffusion.mxu_params``)
+    rec, params = eng.records[0], eng.params
     lab = jnp.asarray(rec.labels, jnp.int32)
     key = serve.batch_key(rec.seeds)
     if rec.decisions is not None:

@@ -23,7 +23,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.config import ModelConfig
+from repro.config import AttentionSpec, BlockSpec, MLPSpec, ModelConfig
 from repro.models import layers as L, transformer as T
 
 TIME_EMB_DIM = 256
@@ -130,8 +130,58 @@ def init_params(key, cfg: ModelConfig, dtype=jnp.float32):
     return p
 
 
+#: the wrapper's weight matrices, each read by one ``L.dense``
+_MXU_WRAPPER = {"patch_in": ("w",), "t_mlp": ("w1", "w2"),
+                "fps_mlp": ("w1", "w2"), "t_block": ("w",),
+                "final_mod": ("w",), "out": ("w",), "y_proj": ("w1", "w2")}
+
+
+def _mxu_block(spec: BlockSpec):
+    """A block's weight matrices, each read by one ``L.dense``, by
+    branch: GQA projections, MLP matrices, the adaLN-zero projection."""
+    names = {}
+    for part in ("mixer", "cross"):
+        a = getattr(spec, part)
+        if isinstance(a, AttentionSpec) and a.kind == "gqa":
+            names[part] = ("wq", "wk", "wv", "wo")
+    if isinstance(spec.ffn, MLPSpec):
+        names["ffn"] = ("w_up", "w_gate", "w_down")
+    if spec.adaln == "zero":
+        names["mod"] = ("w",)
+    return names
+
+
+def _to_bf16(group, names):
+    return {k: v.astype(jnp.bfloat16) if k in names else v
+            for k, v in group.items()}
+
+
+def mxu_params(cfg: ModelConfig, params):
+    """``params`` as a server holds them: on the TPU, where the model names
+    no matmul precision, XLA runs each float32 product as one bfloat16
+    pass and rounds every float32 weight matrix to bfloat16 at the start
+    of each program that reads it.  This rounds them once: every matrix
+    read only by ``L.dense`` comes back in bfloat16, and the products'
+    outputs stay float32.  Norm scales, biases, the label table and the
+    null caption stay float32.  Off the TPU (true float32 products), or
+    under a stated precision (several passes over float32 weights),
+    ``params`` comes back as it is."""
+    if jax.default_backend() != "tpu" or cfg.matmul_precision is not None:
+        return params
+    out = {k: _to_bf16(v, _MXU_WRAPPER[k]) if k in _MXU_WRAPPER else v
+           for k, v in params.items()}
+    backbone = params["backbone"]
+    stages = [tuple({**bp, **{part: _to_bf16(bp[part], names)
+                              for part, names in _mxu_block(b).items()}}
+                    for bp, b in zip(unit, st.unit))
+              for unit, st in zip(backbone["stages"], cfg.stages)]
+    out["backbone"] = {**backbone, "stages": stages}
+    return out
+
+
 def _time_mlp(p, emb):
-    return jax.nn.silu(emb @ p["w1"] + p["b1"]) @ p["w2"] + p["b2"]
+    h = jax.nn.silu(L.dense(emb, p["w1"]) + p["b1"])
+    return L.dense(h, p["w2"]) + p["b2"]
 
 
 def _cond_vector(cfg: ModelConfig, params, t, label=None):
@@ -174,8 +224,9 @@ def _memory(params, memory):
         memory, mask = memory["text"], memory["mask"]
     if memory is not None and "y_proj" in params:
         p = params["y_proj"]
-        memory = L.activation("gelu_tanh")(memory @ p["w1"] + p["b1"])
-        memory = memory @ p["w2"] + p["b2"]
+        memory = L.activation("gelu_tanh")(L.dense(memory, p["w1"])
+                                           + p["b1"])
+        memory = L.dense(memory, p["w2"]) + p["b2"]
     return memory, mask
 
 
@@ -225,13 +276,13 @@ def _apply(cfg: ModelConfig, params, x, t, *, label=None, memory=None,
     _, _, video_shape = token_shape(cfg)
     with jax.named_scope("embed"):
         tok = patchify(cfg, x)
-        h = tok @ params["patch_in"]["w"] + params["patch_in"]["b"]
+        h = L.dense(tok, params["patch_in"]["w"]) + params["patch_in"]["b"]
         # fixed sin-cos positional embedding
         h = h + positions(cfg, h.shape[1])[None].astype(h.dtype)
         cond = _cond_vector(cfg, params, t, label)
         block_cond = cond
         if "t_block" in params:
-            block_cond = (jax.nn.silu(cond) @ params["t_block"]["w"]
+            block_cond = (L.dense(jax.nn.silu(cond), params["t_block"]["w"])
                           + params["t_block"]["b"])
         memory, memory_mask = _memory(params, memory)
     out, aux = T.forward(
@@ -246,10 +297,10 @@ def _apply(cfg: ModelConfig, params, x, t, *, label=None, memory=None,
             shift, scale = jnp.split(fm["table"] + cond[:, None, :], 2,
                                      axis=1)
         else:
-            mod = jax.nn.silu(cond) @ fm["w"] + fm["b"]
+            mod = L.dense(jax.nn.silu(cond), fm["w"]) + fm["b"]
             shift, scale = jnp.split(mod[:, None, :], 2, axis=-1)
         out = out * (1.0 + scale) + shift
-        out = out @ params["out"]["w"] + params["out"]["b"]
+        out = L.dense(out, params["out"]["w"]) + params["out"]["b"]
         return unpatchify(cfg, out), aux
 
 

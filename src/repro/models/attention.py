@@ -230,7 +230,7 @@ def _gqa_qkv(spec: AttentionSpec, params, x, memory=None, dtype=None):
 
     def proj(a, w):
         if dtype is None:
-            return a @ w
+            return L.dense(a, w)
         return a.astype(dtype) @ w.astype(dtype)
 
     q = proj(x, params["wq"])
@@ -289,7 +289,7 @@ def _gqa_full(spec: AttentionSpec, params, x, positions, memory=None,
             if bias.ndim == 2:
                 bias = bias[None]
         out = _sdpa(q, k, v, bias, softcap=spec.logit_softcap, scale=scale)
-    out = out.reshape(x.shape[0], x.shape[1], -1) @ params["wo"]
+    out = L.dense(out.reshape(x.shape[0], x.shape[1], -1), params["wo"])
     return out, (k, v)
 
 
@@ -319,7 +319,7 @@ def _gqa_decode(spec: AttentionSpec, params, x, pos, cache, slot_pos, memory=Non
                       k_valid=(new_slot_pos >= 0)[None, :])
     scale = 1.0 / math.sqrt(spec.head_dim)
     out = _decode_sdpa(spec, q, k, v, bias, scale=scale)
-    out = out.reshape(x.shape[0], 1, -1) @ params["wo"]
+    out = L.dense(out.reshape(x.shape[0], 1, -1), params["wo"])
     return out, {"k": k, "v": v, "slots": new_slot_pos}
 
 

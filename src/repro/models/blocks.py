@@ -91,7 +91,7 @@ def _modulation(spec: BlockSpec, params, cond):
         if spec.adaln == "single":
             m = params["mod"]["table"] + cond.reshape(cond.shape[0], 6, -1)
             return jnp.split(m, 6, axis=1)
-        m = jax.nn.silu(cond) @ params["mod"]["w"] + params["mod"]["b"]
+        m = L.dense(jax.nn.silu(cond), params["mod"]["w"]) + params["mod"]["b"]
         return jnp.split(m[:, None, :], 6, axis=-1)  # each (B, 1, d)
 
 

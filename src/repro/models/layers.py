@@ -24,6 +24,16 @@ def embed_init(key, vocab: int, dim: int, dtype=jnp.float32):
     return w.astype(dtype)
 
 
+def dense(x, w):
+    """``x @ w`` for a weight matrix.  A weight held narrower than ``x``
+    (bfloat16 from ``diffusion.mxu_params``) takes ``x`` rounded to its
+    dtype and gives the product in ``x``'s dtype, accumulated in float32,
+    so the program never widens the weight back."""
+    if jnp.dtype(w.dtype).itemsize >= jnp.dtype(x.dtype).itemsize:
+        return x @ w
+    return jnp.dot(x.astype(w.dtype), w, preferred_element_type=x.dtype)
+
+
 def zeros(shape, dtype=jnp.float32):
     return jnp.zeros(shape, dtype)
 

@@ -19,9 +19,9 @@ def init(key, spec: MLPSpec, d_model: int, dtype=jnp.float32):
 
 def apply(spec: MLPSpec, params, x):
     act = L.activation(spec.activation)
-    up = x @ params["w_up"]
+    up = L.dense(x, params["w_up"])
     if spec.gated:
-        up = act(x @ params["w_gate"]) * up
+        up = act(L.dense(x, params["w_gate"])) * up
     else:
         up = act(up)
-    return up @ params["w_down"]
+    return L.dense(up, params["w_down"])

@@ -51,6 +51,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import diffusion
 from repro.obs import (NULL_TRACER, MetricsRegistry, profiling,
                        run_cache_reports)
 from repro.resilience.faults import NAN_LATENT, STUCK_BATCH, BatchFault
@@ -171,7 +172,18 @@ class ServeEngine:
             raise ValueError(f"adaptive_chunk must be >= 1, got "
                              f"{adaptive_chunk}")
         self.executor = executor
-        self.params = params
+        #: the weights every program of this engine reads: on the TPU the
+        #: model's matrices rounded to bfloat16 here, once, rather than at
+        #: the start of each program (``diffusion.mxu_params``); a test
+        #: double with no model configuration keeps what it was given
+        cfg = getattr(executor, "cfg", None)
+        self.params = (params if cfg is None
+                       else diffusion.mxu_params(cfg, params))
+        #: bytes of the weights that rounding made bfloat16 (0 off the TPU)
+        self.weights_cast_bytes = sum(
+            new.nbytes for old, new in zip(jax.tree.leaves(params),
+                                           jax.tree.leaves(self.params))
+            if new.dtype != old.dtype)
         self.store = store
         self.clock = clock if clock is not None else WallClock()
         self.queue = RequestQueue(self.clock)
@@ -1477,5 +1489,7 @@ class ServeEngine:
         compiles["xla_programs"] = sum(
             self.executor.xla_program_count(kind)
             for kind in self.MODEL_PROGRAM_KINDS)
-        return self.metrics.report(compile_counts=compiles,
-                                   program_budget=self.program_budget())
+        out = self.metrics.report(compile_counts=compiles,
+                                  program_budget=self.program_budget())
+        out["weights_cast_bytes"] = self.weights_cast_bytes
+        return out

@@ -17,7 +17,9 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro import configs
-from repro.core import diffusion
+from repro.core import diffusion, solvers
+from repro.core.executor import SmoothCacheExecutor
+from repro.core.plan import ProgramSig
 from repro.kernels import ops
 
 #: one v5e chip's HBM
@@ -152,3 +154,98 @@ def test_stdit3_forward_fits_one_chip(one_chip):
              + mem.output_size_in_bytes)
     assert mem.argument_size_in_bytes > 4.5e9     # f32 params at full width
     assert total < V5E_HBM_BYTES
+
+
+def _on_chip(tree, one_chip):
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+
+
+def _served_params(cfg, monkeypatch):
+    """The shapes of the weights ``ServeEngine`` holds on the TPU."""
+    params = jax.eval_shape(
+        lambda: diffusion.init_params(jax.random.PRNGKey(0), cfg))
+    with monkeypatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        return jax.eval_shape(lambda p: diffusion.mxu_params(cfg, p), params)
+
+
+def _entry(text):
+    """The entry computation of an optimised HLO module's text."""
+    entry = text[text.index("\nENTRY"):]
+    return entry[:entry.index("\n}")]
+
+
+@pytest.mark.parametrize("weights", ["mxu", "float32"])
+def test_segment_program_rounds_no_weight_on_entry(one_chip, monkeypatch,
+                                                   weights):
+    """DiT-XL/2-256's SmoothCache segment program, 4 rows under CFG, with
+    the FFN skipped and with it computed: with the weights the engine
+    holds (``mxu_params``), its entry computation converts no float32
+    stacked weight; with float32 weights it converts them on every call
+    (the converts of the 256 SmoothCache cell's trace)."""
+    cfg = configs.get("dit-xl-256", "full")
+    params = (_served_params(cfg, monkeypatch) if weights == "mxu" else
+              jax.eval_shape(lambda: diffusion.init_params(
+                  jax.random.PRNGKey(0), cfg)))
+    params = _on_chip(params, one_chip)
+    ex = SmoothCacheExecutor(cfg, solvers.ddim(50), cfg_scale=1.5)
+    rows = 4
+    x = jax.ShapeDtypeStruct((rows,) + tuple(cfg.latent_shape), jnp.float32,
+                             sharding=one_chip)
+    label = jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=one_chip)
+    healthy = jax.ShapeDtypeStruct((rows,), jnp.bool_, sharding=one_chip)
+    step = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    kloop = _on_chip(jax.eval_shape(lambda: jax.random.PRNGKey(0)), one_chip)
+    state = _on_chip(jax.eval_shape(ex.solver.init_state), one_chip)
+    structs = ex._branch_structs(params, x, label, None)
+    cache = [tuple({"ffn": s["ffn"]} for s in stage) for stage in structs]
+    cache = _on_chip(cache, one_chip)
+    for skip_ffn in (True, False):
+        sig = ProgramSig(mask=(("attn", False), ("ffn", skip_ffn)),
+                         collect=() if skip_ffn else ("ffn",))
+        # as served: the TPU's default precision, not the tests' highest
+        with jax.default_matmul_precision("default"):
+            text = ex._get_sig_loop_fn(sig).lower(
+                params, x, state, cache, healthy, step, step, kloop, label,
+                None).compile().as_text()
+        entry = _entry(text)
+        stacked = re.findall(r"(%\S+) = f32\[28,\d+,\d+\]\S* parameter\(",
+                             entry)
+        # mod.w, q, k, v, o and the MLP's two, all read or not
+        held = re.findall(r"= bf16\[28,\d+,\d+\]\S* parameter\(", entry)
+        assert (len(stacked), len(held)) == ((7, 0) if weights == "float32"
+                                             else (0, 7))
+        converted = [p for p in stacked if f" convert({p})" in entry]
+        if weights == "mxu":
+            assert "convert(%params" not in entry
+        else:
+            # mod.w and wo, and with the FFN computed w_up and w_down (the
+            # q, k, v projections are converted inside a relayout copy)
+            assert len(converted) == (2 if skip_ffn else 4), converted
+
+
+def test_stdit3_at_high_reads_float32_weights(one_chip, monkeypatch):
+    """Open-Sora states matmul precision ``high``: the engine keeps its
+    float32 weights, and the forward it lowers takes no bfloat16
+    parameter."""
+    cfg = configs.get("opensora-v12", "full")
+    params = _on_chip(_served_params(cfg, monkeypatch), one_chip)
+    assert {a.dtype for a in jax.tree.leaves(params)} == {jnp.dtype("float32")}
+    rows = 4
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    memory = {"text": spec((rows, cfg.text_len, cfg.text_dim)),
+              "mask": spec((rows, cfg.text_len), jnp.bool_)}
+
+    def forward(p, x, t, memory):
+        return diffusion.apply(cfg, p, x, t, memory=memory)[0]
+
+    text = jax.jit(forward).lower(
+        params, spec((rows,) + tuple(cfg.latent_shape)), spec((rows,)),
+        memory).as_text()
+    main = text[text.index("func.func public @main"):]
+    main = main[:main.index("{\n")]
+    assert "bf16" not in main and "xf32>" in main
